@@ -101,6 +101,7 @@ def _metadata() -> dict:
         "jax_version": jax.__version__,
         "jax_backend": jax.default_backend(),
         "device_platform": devices[0].platform if devices else "none",
+        "device_kind": devices[0].device_kind if devices else "none",
         "device_count": len(devices),
         # "data8" under a sharded-bench process ($REPRO_MESH_SHAPE or an
         # engine mesh_context); None on single-device runs. Part of the
@@ -175,6 +176,9 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro import obs
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.trace or args.metrics_out:
         obs.enable()

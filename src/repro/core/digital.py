@@ -35,7 +35,7 @@ def mlp_forward(params: Params, x: jax.Array, activation: str = "sigmoid") -> ja
            "relu": jax.nn.relu}[activation]
     a = x
     for i, (w, b) in enumerate(params):
-        z = a @ w + b
+        z = jnp.matmul(a, w, precision=jax.lax.Precision.HIGHEST) + b
         a = z if i == len(params) - 1 else act(z)
     return a
 

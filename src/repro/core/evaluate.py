@@ -27,8 +27,8 @@ from repro.obs import prof as obs_prof
 from repro.core.digital import Params, mlp_forward
 from repro.core.imac import IMACConfig, build_plans, layer_latency, linear_forward
 from repro.core.mapping import MappedLayer, map_network
+from repro.core.partition import ordered_sum
 from repro.core.solver import CircuitParams, SolveOptions, suggest_iters
-from repro.distributed.compat import shard_map_compat
 from repro.distributed.sweep import (
     MeshPlan,
     pad_count,
@@ -526,7 +526,7 @@ def _evaluate_batch(
                 noise_per_config=noise_per_config,
                 dtype=dtype,
             )
-            powers.append(jnp.mean(power, axis=-1))   # (C,)
+            powers.append(ordered_sum(power) / power.shape[-1])  # (C,)
             residuals.append(residual)                # (C,)
             sweeps.append(swp)                        # scalar per layer
         pred = jnp.argmax(a, axis=-1)                 # (C, batch)
@@ -555,11 +555,12 @@ def _evaluate_batch(
                 return forward_all(gp, gn, kk, sc, xb, None)
 
             inner = obs_prof.instrument_jit(
-                jax.jit(shard_map_compat(
+                jax.jit(jax.shard_map(
                     forward_nokey,
                     mesh=s_mesh,
                     in_specs=stacked_specs + (P(),),
                     out_specs=out_specs,
+                    check_vma=False,
                 )),
                 "solve_chunk",
             )
@@ -568,11 +569,12 @@ def _evaluate_batch(
                 return inner(gp, gn, kk, sc, xb)
         else:
             run_chunk = obs_prof.instrument_jit(
-                jax.jit(shard_map_compat(
+                jax.jit(jax.shard_map(
                     forward_all,
                     mesh=s_mesh,
                     in_specs=stacked_specs + (P(), P()),
                     out_specs=out_specs,
+                    check_vma=False,
                 )),
                 "solve_chunk",
             )
@@ -603,7 +605,7 @@ def _evaluate_batch(
             layer_sweeps = swp                 # (L,), batch-wide per layer
     obs_prof.sample_memory("solve")
     pred = jnp.concatenate(preds, axis=1)                      # (C, n)
-    per_layer_power = jnp.sum(jnp.stack(powers), axis=0) / n   # (C, L)
+    per_layer_power = ordered_sum(jnp.stack(powers), axis=0) / n   # (C, L)
     worst_res = jnp.max(jnp.stack(residuals), axis=0)          # (C, L)
     if shard is not None:
         # Drop the pad lanes (replicas of config 0) before measurement.

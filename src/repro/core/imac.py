@@ -31,6 +31,7 @@ from repro.core.partition import (
     PartitionPlan,
     auto_partition,
     combine_outputs,
+    ordered_sum,
     plan_partition,
     tile_inputs,
     tile_matrix,
@@ -194,8 +195,15 @@ def linear_forward(
     sweeps = jnp.zeros((), jnp.int32)
     if not parasitics:
         g_diff = (g_pos - g_neg).astype(dtype)
-        i_diff = jnp.einsum("...mn,...bm->...bn", g_diff, v)
-        p_dev = jnp.einsum("...mn,...bm->...b", g_pos + g_neg, v**2)
+        # HIGHEST: on TPU an f32 dot defaults to bf16 passes, which
+        # would put bf16 rounding into simulated currents and power.
+        highest = jax.lax.Precision.HIGHEST
+        i_diff = jnp.einsum(
+            "...mn,...bm->...bn", g_diff, v, precision=highest
+        )
+        p_dev = jnp.einsum(
+            "...mn,...bm->...b", g_pos + g_neg, v**2, precision=highest
+        )
         residual = jnp.zeros(g_pos.shape[:-2], dtype)
     else:
         tiles_p = tile_matrix(g_pos.astype(dtype), plan)
@@ -212,7 +220,7 @@ def linear_forward(
         i_pos = combine_outputs(sol.i_out[..., :t, :], plan)
         i_neg = combine_outputs(sol.i_out[..., t:, :], plan)
         i_diff = i_pos - i_neg
-        p_dev = crossbar_power(g_b, v_all, sol, cp).sum(axis=-1)
+        p_dev = ordered_sum(crossbar_power(g_b, v_all, sol, cp))
         residual = jnp.max(sol.residual, axis=(-1, -2))
         sweeps = jnp.asarray(sol.sweeps, jnp.int32)
 

@@ -129,9 +129,31 @@ def combine_outputs(per_tile: jnp.ndarray, plan: PartitionPlan) -> jnp.ndarray:
       (..., total_cols) combined currents (padding columns dropped).
     """
     t = per_tile.reshape(*per_tile.shape[:-2], plan.hp, plan.vp, plan.cols)
-    summed = t.sum(axis=-3)  # partial sums over horizontal partitions
+    summed = ordered_sum(t, axis=-3)  # partial sums over horizontal partitions
     out = summed.reshape(*summed.shape[:-2], plan.vp * plan.cols)
     return out[..., : plan.total_cols]
+
+
+def ordered_sum(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
+    """Sum over one axis in a fixed pairwise order.
+
+    A reduce op adds in whatever order the memory layout the compiler
+    picks for it gives, and on TPU that layout follows the shapes around
+    the op: the same configuration's power summed inside a stack of four
+    configurations and inside a stack of one differed in the last bit.
+    Halving with elementwise adds fixes the order, so per-configuration
+    results do not depend on how many configurations share a program
+    (sharded vs unsharded sweeps). Odd lengths pad with an exact zero.
+    """
+    axis = axis % x.ndim
+    while x.shape[axis] > 1:
+        if x.shape[axis] % 2:
+            pad = [(0, 0)] * x.ndim
+            pad[axis] = (0, 1)
+            x = jnp.pad(x, pad)
+        lo, hi = jnp.split(x, 2, axis=axis)
+        x = lo + hi
+    return jnp.squeeze(x, axis=axis)
 
 
 def plan_topology(

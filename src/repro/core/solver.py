@@ -48,6 +48,7 @@ from repro.core.backends import (
     TridiagFn,
     get_backend,
 )
+from repro.core.partition import ordered_sum
 
 
 @dataclasses.dataclass(frozen=True)
@@ -529,7 +530,9 @@ def suggest_iters(m: int, n: int) -> int:
 
 def solve_ideal(g: jax.Array, v_in: jax.Array) -> jax.Array:
     """Ideal crossbar (no parasitics): i_out = g^T v. (..., M, N) x (..., M)."""
-    return jnp.einsum("...mn,...m->...n", g, v_in)
+    return jnp.einsum(
+        "...mn,...m->...n", g, v_in, precision=jax.lax.Precision.HIGHEST
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -662,23 +665,30 @@ def crossbar_power(
     sol: CrossbarSolution,
     cp: CircuitParams,
 ) -> jax.Array:
-    """Total dissipated power (W) of solved tiles; reduces last two dims."""
+    """Total dissipated power (W) of solved tiles; reduces last two dims.
+
+    Sums run in `ordered_sum`'s fixed order, so a tile's power has the
+    same bits whatever batch it is solved in.
+    """
+    def sum2(x):
+        return ordered_sum(ordered_sum(x, axis=-1), axis=-1)
+
     vr, vc = sol.vr, sol.vc
-    p_dev = jnp.sum(g * (vr - vc) ** 2, axis=(-1, -2))
+    p_dev = sum2(g * (vr - vc) ** 2)
     ndim = p_dev.ndim
     dr = jnp.diff(vr, axis=-1)
-    p_row = _align(cp.g_row, ndim, dr.dtype) * jnp.sum(dr**2, axis=(-1, -2))
+    p_row = _align(cp.g_row, ndim, dr.dtype) * sum2(dr**2)
     dc = jnp.diff(vc, axis=-2)
-    p_col = _align(cp.g_col, ndim, dc.dtype) * jnp.sum(dc**2, axis=(-1, -2))
-    p_src = _align(cp.g_source, ndim, vr.dtype) * jnp.sum(
-        (v_in - vr[..., :, 0]) ** 2, axis=-1
+    p_col = _align(cp.g_col, ndim, dc.dtype) * sum2(dc**2)
+    p_src = _align(cp.g_source, ndim, vr.dtype) * ordered_sum(
+        (v_in - vr[..., :, 0]) ** 2
     )
-    p_tia = _align(cp.g_tia, ndim, vc.dtype) * jnp.sum(
-        vc[..., -1, :] ** 2, axis=-1
-    )
+    p_tia = _align(cp.g_tia, ndim, vc.dtype) * ordered_sum(vc[..., -1, :] ** 2)
     return p_dev + p_row + p_col + p_src + p_tia
 
 
 def ideal_power(g: jax.Array, v_in: jax.Array) -> jax.Array:
     """Power of the ideal crossbar (columns at virtual ground)."""
-    return jnp.einsum("...mn,...m->...", g, v_in**2)
+    return jnp.einsum(
+        "...mn,...m->...", g, v_in**2, precision=jax.lax.Precision.HIGHEST
+    )

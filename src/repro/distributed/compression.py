@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.compat import shard_map_compat
-
 BLOCK = 256
 
 
@@ -115,11 +113,12 @@ def compressed_allreduce(
         return means, news
 
     specs = jax.tree_util.tree_map(lambda _: P(), grads)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(specs, specs),
         out_specs=(specs, specs),
+        check_vma=False,
     )
     mean, new_res = fn(grads, state.residual)
     return mean, CompressionState(residual=new_res)

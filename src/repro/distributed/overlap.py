@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.compat import shard_map_compat
-
 
 def ring_allgather_matmul(
     x: jax.Array,
@@ -65,10 +63,11 @@ def ring_allgather_matmul(
         acc, _ = jax.lax.fori_loop(0, n, step, (acc0, w_l))
         return acc
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P(axis_name, None)),
         out_specs=P(),
+        check_vma=False,
     )
     return fn(x, w)

@@ -30,7 +30,7 @@ the inner loop.
 
 VMEM budget: ~17 lane-block buffers of the padded tile, i.e. roughly
 ``17 × LB × pad8(M) × pad128(N) × 4B`` for f32 — `ops.fused_lane_block`
-picks LB against an 8 MB budget (≈ LB=16 at 32×32) and reports 0 when
+picks LB against an 8 MB budget (LB=30 at 32×32) and reports 0 when
 even LB=1 does not fit (≥ ~352×352 tiles), in which case the solver
 falls back to the per-half-sweep ``"pallas"`` backend.
 """
@@ -135,8 +135,11 @@ def _gs_fused_kernel(
         vc_gs = col_solve(vr)
         vc_new = vc_old + omega * (vc_gs - vc_old)
         vcs_ref[...] = vc_new
+        # One axis at a time: Mosaic aborts the process (a layout check
+        # failure, not a Python error) on a two-axis keepdims reduction.
+        delta = jnp.abs(vc_new - vc_old)
         res_ref[...] = jnp.max(
-            jnp.abs(vc_new - vc_old), axis=(1, 2), keepdims=True
+            jnp.max(delta, axis=2, keepdims=True), axis=1, keepdims=True
         )
         return 0
 

@@ -10,6 +10,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core.backends import on_tpu
 from repro.core.devices import DeviceTech, get_tech
 from repro.kernels.imac_mvm.kernel import imac_mvm_padded
 from repro.kernels.imac_mvm.ref import imac_mvm_ref
@@ -41,7 +42,7 @@ def imac_mvm(
     """Quantised differential analog MVM. x: (..., K) in [0,1] digital
     units; w: (K, N) in [-1,1] normalised weights. Returns (..., N)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     lead = x.shape[:-1]
     k = x.shape[-1]
     xf = x.reshape(-1, k)

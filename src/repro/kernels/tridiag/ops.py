@@ -2,8 +2,7 @@
 
 Accepts the solver's native (..., N) layout, flattens the batch onto the
 lane axis, pads to 128 and dispatches to the kernel. On non-TPU backends
-it runs in interpret mode (or falls back to the scan oracle for speed —
-interpret mode executes the kernel body in Python per grid step).
+it runs in interpret mode.
 """
 from __future__ import annotations
 
@@ -12,12 +11,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.backends import on_tpu
 from repro.kernels.tridiag.kernel import LANES, tridiag_nb
-from repro.kernels.tridiag.ref import tridiag_ref
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -67,8 +62,3 @@ def tridiag(
         ]
     x = tridiag_nb(*args, interpret=interpret)
     return x[:, :batch].T.reshape(shape)
-
-
-def tridiag_or_ref(*args, use_kernel: bool = True, **kw):
-    """Select kernel vs oracle (tests use both)."""
-    return tridiag(*args, **kw) if use_kernel else tridiag_ref(*args)

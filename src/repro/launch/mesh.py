@@ -8,6 +8,7 @@ before any import) should ever see 512 placeholder devices.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -23,10 +24,13 @@ def make_sweep_mesh(n_devices: "int | None" = None, axis: str = "data"):
     The sharded sweep engine (repro.distributed.sweep.MeshPlan) splits
     each structure group's leading config axis across this mesh; a
     single named axis keeps the shard_map specs and the solver's
-    cross-shard convergence pmax trivially aligned.
+    cross-shard convergence pmax trivially aligned. The axis is
+    `AxisType.Auto`: the engine trims pad lanes and indexes per-config
+    outputs with plain slicing, which an explicit-sharding axis (the
+    `jax.make_mesh` default) rejects for a sharded operand.
     """
     n = n_devices or len(jax.devices())
-    return jax.make_mesh((n,), (axis,))
+    return jax.make_mesh((n,), (axis,), axis_types=(AxisType.Auto,))
 
 
 def make_dev_mesh(n_devices: "int | None" = None):

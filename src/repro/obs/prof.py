@@ -38,14 +38,13 @@ import importlib
 
 _trace = importlib.import_module("repro.obs.trace")
 
-#: Nominal peak FLOP/s per device platform for roofline utilization.
-#: Override with REPRO_OBS_PEAK_FLOPS (floats accepted, e.g. "1.97e14").
-#: CPU peaks vary too much across hosts to guess — utilization is only
-#: reported when a peak is known.
-PLATFORM_PEAK_FLOPS = {
-    "tpu": 1.97e14,  # TPU v4 bf16 MXU peak per chip
-    "gpu": None,
-    "cpu": None,
+#: Published per-chip peaks for roofline shares, keyed by
+#: `jax.Device.device_kind`. Source: Google Cloud documentation, "TPU
+#: v5e" (197 TFLOP/s bf16, 819 GB/s HBM). A TPU kind missing here is an
+#: error, not a default; CPU and GPU hosts report no peak. Override the
+#: FLOP/s peak with REPRO_OBS_PEAK_FLOPS (e.g. "1.97e14").
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bytes_per_s": 819e9},
 }
 
 _cost_flag: "Optional[bool]" = None
@@ -80,22 +79,36 @@ def reset_cost() -> None:
     _cost_seen.clear()
 
 
-def peak_flops(platform: "Optional[str]" = None) -> "Optional[float]":
-    """Peak device FLOP/s for utilization, or None when unknown."""
+def device_peaks(kind: "Optional[str]" = None) -> "Optional[dict]":
+    """Published peaks of a device kind (default: the first device).
+
+    Returns None off-TPU. Raises KeyError for a TPU kind without a row
+    in `DEVICE_PEAKS`.
+    """
+    if kind is None:
+        import jax
+
+        kind = jax.devices()[0].device_kind
+    if kind in DEVICE_PEAKS:
+        return DEVICE_PEAKS[kind]
+    if kind.startswith("TPU"):
+        raise KeyError(
+            f"no published peaks for device kind {kind!r}; add a row to "
+            "repro.obs.prof.DEVICE_PEAKS with its source"
+        )
+    return None
+
+
+def peak_flops(kind: "Optional[str]" = None) -> "Optional[float]":
+    """Peak device FLOP/s for utilization, or None off-TPU."""
     env = os.environ.get("REPRO_OBS_PEAK_FLOPS")
     if env:
         try:
             return float(env)
         except ValueError:
             pass
-    if platform is None:
-        try:
-            import jax
-
-            platform = jax.devices()[0].platform
-        except Exception:
-            return None
-    return PLATFORM_PEAK_FLOPS.get(platform)
+    peaks = device_peaks(kind)
+    return peaks["flops"] if peaks else None
 
 
 @contextlib.contextmanager
@@ -234,7 +247,7 @@ def instrument_jit(fn: Callable, name: str) -> Callable:
         signature (gauges ``hlo_flops`` / ``hlo_bytes_accessed``) and,
         on steady-state calls, derives ``achieved_flops_per_s{fn=name}``
         = flops / measured seconds plus ``roofline_utilization``
-        against `peak_flops` when a platform peak is known.
+        against `peak_flops` when a device peak is known.
     """
     traced_fn = _trace.instrument_jit(fn, name)
 

@@ -3,7 +3,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.distributed.compat import shard_map_compat
 from repro.launch.hlo_analysis import collective_stats, compute_stats
 
 
@@ -62,7 +61,9 @@ def test_collective_counting_with_psum():
     def f(x):
         return jax.lax.psum(x, "data")
 
-    fn = shard_map_compat(f, mesh=mesh, in_specs=P(), out_specs=P())
+    fn = jax.shard_map(
+        f, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False
+    )
     c = jax.jit(fn).lower(jax.ShapeDtypeStruct((1024,), jnp.float32)).compile()
     stats = collective_stats(c.as_text())
     # single-device psum may be optimized away; stats must not crash and

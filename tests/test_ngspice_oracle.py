@@ -175,7 +175,7 @@ def test_ngspice_dc_crossbar_vs_backends():
         assert op.voltage(node) == pytest.approx(want, rel=1e-6, abs=1e-12), node
 
     xb = lower_crossbar(circ)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         dense = xb.node_voltages(xb.solve_dense())
     for node, want in dense.items():
         assert op.voltage(node) == pytest.approx(want, rel=1e-5, abs=1e-9), node
@@ -279,7 +279,7 @@ def test_ngspice_accepts_generated_netlist():
     gn = np.asarray(tile_matrix(jnp.asarray(la.g_neg), la.plan))[0]
     v_in = np.concatenate([sample * net.v_unit, [net.v_unit]])
     cp = net.to_config().circuit_params(la.plan.rows, la.plan.cols)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         i_p = np.asarray(solve_dense_mna(jnp.asarray(gp), jnp.asarray(v_in), cp).i_out)
         i_n = np.asarray(solve_dense_mna(jnp.asarray(gn), jnp.asarray(v_in), cp).i_out)
     z = (i_p - i_n) * la.neuron.sense_scale

@@ -9,6 +9,7 @@ from hypothesis import given, settings, strategies as st
 from repro.core.partition import (
     auto_partition,
     combine_outputs,
+    ordered_sum,
     plan_partition,
     plan_topology,
     tile_inputs,
@@ -99,6 +100,23 @@ def test_partitioned_ideal_mvm_equals_full(fan_in, fan_out, hp, vp):
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(v @ g), rtol=2e-4, atol=1e-5
     )
+
+
+@pytest.mark.parametrize("length", [1, 2, 7, 13, 64])
+@pytest.mark.parametrize("axis", [0, 1, -1])
+def test_ordered_sum_matches_sum_and_ignores_batch(length, axis):
+    """ordered_sum is a sum, and a slice of a stacked batch reduces to
+    the same bits as that slice reduced alone (sharded-sweep identity)."""
+    shape = [3, 5, 4]
+    shape[axis] = length
+    x = jax.random.uniform(jax.random.PRNGKey(length), (4, *shape))
+    row_axis = axis % 3
+    got = jax.jit(lambda x: ordered_sum(x, axis=row_axis + 1))(x)
+    want = np.sum(np.asarray(x, np.float64), axis=row_axis + 1)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6)
+    for c in range(x.shape[0]):
+        alone = jax.jit(lambda x: ordered_sum(x, axis=axis))(x[c])
+        np.testing.assert_array_equal(np.asarray(got[c]), np.asarray(alone))
 
 
 def test_validation_errors():

@@ -403,7 +403,12 @@ def test_peak_flops_env_override(monkeypatch):
     monkeypatch.setenv("REPRO_OBS_PEAK_FLOPS", "garbage")
     assert prof.peak_flops("cpu") is None
     monkeypatch.delenv("REPRO_OBS_PEAK_FLOPS")
-    assert prof.peak_flops("tpu") == prof.PLATFORM_PEAK_FLOPS["tpu"]
+    assert prof.peak_flops("TPU v5 lite") == pytest.approx(197e12)
+    assert prof.device_peaks("TPU v5 lite")["hbm_bytes_per_s"] == (
+        pytest.approx(819e9)
+    )
+    with pytest.raises(KeyError, match="TPU v9"):
+        prof.peak_flops("TPU v9")
 
 
 # ---------------------------------------------------------------------------

@@ -127,7 +127,7 @@ def check_lowered_matches_dense_mna(
     np.testing.assert_allclose(xb.v_in, v, rtol=1e-12)
 
     op = solve_dc(circ)  # generic nodal solve of the parsed text
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         got = xb.node_voltages(xb.solve_dense())
     assert got, "no node voltages recovered"
     for node, want in got.items():

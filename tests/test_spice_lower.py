@@ -1,7 +1,7 @@
 """Lowering tests: parsed netlists -> MNA structures (repro.spice.lower).
 
 The tight (1e-6-relative) parser-vs-dense-MNA comparisons run under
-`jax.experimental.enable_x64`: ideal crossbars lower with R_WIRE_EPS
+`jax.enable_x64(True)`: ideal crossbars lower with R_WIRE_EPS
 wire segments (1e-6 ohm), which makes the float32 dense solve
 ill-conditioned while the float64 one is exact to round-off.
 """
@@ -229,7 +229,7 @@ def test_lower_ideal_crossbar_matches_generic_solve():
     circ = parse_netlist(ideal_crossbar(g, v))
     xb = lower_crossbar(circ)
     op = solve_dc(circ)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         got = xb.node_voltages(xb.solve_dense())
     for node, want in op.voltages.items():
         if node in got:
@@ -245,7 +245,7 @@ def test_lower_wired_crossbar_matches_generic_solve():
     np.testing.assert_allclose(xb.g, g, rtol=1e-9)
     assert xb.r_row == 13.8 and xb.r_col == 13.8
     op = solve_dc(circ)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         got = xb.node_voltages(xb.solve_dense())
     for node, want in got.items():
         assert want == pytest.approx(op.voltages[node], rel=1e-9, abs=1e-15)
