@@ -1,0 +1,8 @@
+"""95th percentile of the time from sending a request to its result on the host."""
+import numpy as np
+
+from benchlib import readers
+
+
+def read(ctx):
+    return float(np.percentile(readers.latencies_ms(ctx), 95))
