@@ -1,0 +1,6 @@
+"""JAX traces per design point, from the program's jit_traces_total counter (sweep cells)."""
+from benchlib import build
+
+
+def read(ctx):
+    return build.jit_traces_per_point(ctx)

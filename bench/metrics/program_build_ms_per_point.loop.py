@@ -1,0 +1,6 @@
+"""Self time of the program's trace, lower and executable-fetch spans per request (loop cells)."""
+from benchlib import build
+
+
+def read(ctx):
+    return build.build_ms_per_point(ctx)

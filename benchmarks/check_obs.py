@@ -8,7 +8,7 @@ layer actually observed the run:
     samples only) and contains the key series;
   * the trace is valid trace_event JSON with complete ("X") spans,
     including at least one compile-phase and one steady-state
-    ``solve_chunk`` span;
+    ``solve_chunk`` span and one ``jit_trace`` build span;
   * any additional arguments are ``BENCH_<name>.json`` payloads checked
     against the v2 schema (`validate_bench_payload`).
 
@@ -39,6 +39,7 @@ REQUIRED_SERIES = (
     "solver_sweeps",
     "cache_hits_total",
     "backend_fallback_total",
+    "jit_traces_total",
 )
 
 _SAMPLE = re.compile(
@@ -101,9 +102,13 @@ def check_trace(path: str) -> None:
             f"trace {path} has no steady-state solve_chunk[run] span; "
             f"got {sorted(names)}"
         )
+    if "jit_trace" not in names:
+        raise SystemExit(
+            f"trace {path} has no jit_trace build span; got {sorted(names)}"
+        )
     print(
         f"ok: {path} has {len(complete)} spans incl. compile/run "
-        f"solve_chunk split"
+        f"solve_chunk split and jit_trace build spans"
     )
 
 

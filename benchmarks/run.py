@@ -33,7 +33,7 @@ with ``python -m benchmarks.regress`` and render it with
 Deep profiling (repro.obs.prof): ``--jax-profile DIR`` (or
 ``REPRO_OBS_JAX_PROFILE``) captures a jax.profiler device trace of the
 whole run; ``--cost`` (or ``REPRO_OBS_COST=1``) records per-jitted-fn
-HLO cost analysis (hlo_flops / achieved_flops_per_s gauges).
+HLO cost analysis (hlo_flops / hlo_bytes_accessed gauges).
 
 Usage: PYTHONPATH=src python -m benchmarks.run [--only table3,table4,...]
            [--trace trace.json] [--metrics-out metrics.prom]

@@ -357,8 +357,10 @@ def _evaluate_batch(
 
     Stage spans follow the pipeline: map (mapWB / stacking) → stamp
     (electrical-scalar assembly) → solve (chunked circuit solves, with
-    compile-vs-run split via obs.instrument_jit) → measure (host-side
-    reduction to IMACResults + solver-telemetry histograms).
+    compile-vs-run split via obs.instrument_jit) → device_wait (host
+    blocked on the chunks' outputs; only while observability is on) →
+    measure (host-side reduction to IMACResults + solver-telemetry
+    histograms).
     """
     topology = [params[0][0].shape[0]] + [w.shape[1] for w, _ in params]
     key0 = structure_key(topology, cfgs[0])
@@ -540,7 +542,7 @@ def _evaluate_batch(
     obs_prof.sample_memory("stamp")
 
     # prof.instrument_jit = the tracer's compile-vs-run span split plus
-    # opt-in HLO cost analysis (hlo_flops / achieved_flops_per_s).
+    # opt-in HLO cost analysis (hlo_flops / hlo_bytes_accessed).
     if shard is not None:
         stacked_specs = jax.tree_util.tree_map(
             lambda t: stacked_spec(t, s_mesh, s_axis), (g_pos, g_neg, k, scal)
@@ -589,7 +591,9 @@ def _evaluate_batch(
         if noise_key is not None
         else [None] * n_chunks
     )
-    preds, powers, residuals, layer_sweeps = [], [], [], None
+    preds, powers, residuals = [], [], []
+    # (L,) batch-wide sweep counts of every chunk, for the telemetry only.
+    chunk_sweeps = [] if obs.enabled() else None
     solve_attrs = {"chunks": n_chunks, "n_samples": n}
     if shard is not None:
         solve_attrs["devices"] = n_shards
@@ -602,7 +606,13 @@ def _evaluate_batch(
             preds.append(pred)                 # (C, B)
             powers.append(pwr * xb.shape[0])   # weight by chunk size
             residuals.append(res)
-            layer_sweeps = swp                 # (L,), batch-wide per layer
+            if chunk_sweeps is not None:
+                chunk_sweeps.append(swp)
+    if obs.enabled():
+        # The solve span ends once the chunks are enqueued; name the time
+        # the host then waits for the chip.
+        with obs.trace("device_wait"):
+            jax.block_until_ready((preds, powers, residuals, chunk_sweeps))
     obs_prof.sample_memory("solve")
     pred = jnp.concatenate(preds, axis=1)                      # (C, n)
     per_layer_power = ordered_sum(jnp.stack(powers), axis=0) / n   # (C, L)
@@ -620,8 +630,9 @@ def _evaluate_batch(
         h_res = obs.histogram(
             "solver_residual", buckets=obs.RESIDUAL_BUCKETS
         )
-        for layer in range(n_layers):
-            h_sw.observe(int(layer_sweeps[layer]))
+        for swp in jax.device_get(chunk_sweeps):
+            for value in swp:
+                h_sw.observe(int(value))
         for value in jnp.ravel(worst_res):
             h_res.observe(float(value))
         obs.counter("solver_chunks_total").inc(n_chunks)

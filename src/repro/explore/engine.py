@@ -391,8 +391,6 @@ def run_sweep(
         derived = f"points={len(items)};groups={len(groups)}"
         if plan is not None:
             derived += f";mesh={plan.shape_str()}"
-        if obs.enabled() and elapsed > 0:
-            obs.gauge("sweep_points_per_s").set(len(items) / elapsed)
         # Opt-in perf-trajectory entry (obs enabled + REPRO_OBS_LEDGER
         # set): us/point with the metrics snapshot riding along. Sharded
         # runs tag their mesh shape so regression baselines stay

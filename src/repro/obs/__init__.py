@@ -7,8 +7,11 @@ backend registry:
   * **span tracer** — ``with obs.trace("solve_dc"): ...`` /
     ``@obs.traced()``; thread-safe, nestable; exports Chrome
     ``trace_event`` JSON (`export_chrome_trace`) and a plain-text tree
-    (`span_tree`). `instrument_jit` splits jitted calls into
-    ``[compile]`` vs ``[run]`` spans.
+    (`span_tree`), each span mirrored as a ``jax.profiler``
+    annotation. JAX's compile events become build spans (``jit_trace``,
+    ``jit_lower``, ``executable_fetch``, ``backend_compile``) with
+    counters; `instrument_jit` splits jitted calls into ``[compile]``
+    vs ``[run]`` spans by those events.
   * **metrics registry** — `counter` / `gauge` / `histogram` (fixed
     exponential buckets) with Prometheus text (`export_prometheus`) and
     JSON (`snapshot` / `export_json`) exporters.
@@ -19,7 +22,7 @@ backend registry:
 Everything is gated on one process-wide flag (`enable` / `disable` /
 ``REPRO_OBS=1``); when disabled, every entry point is a single flag
 check returning shared no-op handles — zero allocations on the hot
-path. See README § Observability.
+path — and no listener is registered with JAX. See README § Observability.
 
 The continuous-performance tier lives in submodules: `repro.obs.ledger`
 (append-only JSONL run ledger), `repro.obs.regress` (noise-aware
@@ -47,7 +50,8 @@ from repro.obs.metrics import (
     snapshot,
 )
 from repro.obs.metrics import reset as _reset_metrics
-from repro.obs.state import disable, enable, enabled
+from repro.obs import state
+from repro.obs.state import enabled
 from repro.obs.trace import (
     Span,
     add_instant,
@@ -59,8 +63,26 @@ from repro.obs.trace import (
     trace,
     traced,
 )
+from repro.obs.trace import listen as _listen
 from repro.obs.trace import reset as _reset_traces
 from repro.obs import ledger, prof, regress  # noqa: E402  (submodules)
+
+
+def enable() -> None:
+    """Turn observability on for the rest of the process (idempotent) and
+    listen to JAX's compile events."""
+    state.enable()
+    _listen(True)
+
+
+def disable() -> None:
+    """Turn observability off and stop listening to JAX; already-recorded
+    data is kept."""
+    state.disable()
+    _listen(False)
+
+
+_listen(enabled())  # REPRO_OBS=1 listens from import on
 
 
 def reset() -> None:

@@ -1,5 +1,5 @@
 """Deep profiling hooks: jax.profiler capture, device-memory watermarks,
-and per-jitted-fn HLO cost analysis joined with measured runtimes.
+and per-jitted-fn HLO cost analysis.
 
 Three opt-in layers on top of the base tracer/metrics:
 
@@ -15,18 +15,15 @@ Three opt-in layers on top of the base tracer/metrics:
   * `instrument_jit(fn, name)` — the tracer's compile-vs-run span split
     *plus*, when cost profiling is enabled (``REPRO_OBS_COST=1`` or
     `enable_cost`), a one-time HLO ``cost_analysis()`` per input
-    signature recording ``hlo_flops`` / ``hlo_bytes_accessed`` gauges,
-    a per-call ``jit_seconds`` histogram, and — for steady-state calls
-    — ``achieved_flops_per_s`` and ``roofline_utilization`` against
-    `peak_flops`. Cost analysis relowers the function once per new
-    signature, which is why it is opt-in.
+    signature recording ``hlo_flops`` / ``hlo_bytes_accessed`` gauges.
+    Cost analysis relowers the function once per new signature, which
+    is why it is opt-in.
 """
 from __future__ import annotations
 
 import contextlib
 import functools
 import os
-import time
 from typing import Callable, Optional
 
 from repro.obs import metrics, state
@@ -236,18 +233,11 @@ def _record_cost(jitfn: Callable, name: str, args, kw) -> "Optional[dict]":
 
 
 def instrument_jit(fn: Callable, name: str) -> Callable:
-    """Span split + runtime histogram + opt-in HLO cost join.
+    """Span split + opt-in HLO cost record.
 
     Wraps `trace.instrument_jit` (``name[compile]`` / ``name[run]``
-    spans, block-until-ready timing) and additionally:
-
-      * observes every traced call into a ``jit_seconds{fn=name}``
-        histogram (`metrics.SECONDS_BUCKETS`);
-      * with cost profiling on, runs `hlo_cost` once per new input
-        signature (gauges ``hlo_flops`` / ``hlo_bytes_accessed``) and,
-        on steady-state calls, derives ``achieved_flops_per_s{fn=name}``
-        = flops / measured seconds plus ``roofline_utilization``
-        against `peak_flops` when a device peak is known.
+    spans) and, with cost profiling on, runs `hlo_cost` once per new
+    input signature (gauges ``hlo_flops`` / ``hlo_bytes_accessed``).
     """
     traced_fn = _trace.instrument_jit(fn, name)
 
@@ -255,35 +245,15 @@ def instrument_jit(fn: Callable, name: str) -> Callable:
     def wrapped(*args, **kw):
         if not state._enabled:
             return fn(*args, **kw)
-        t0 = time.perf_counter()
         out = traced_fn(*args, **kw)
-        dt = time.perf_counter() - t0
-        metrics.histogram(
-            "jit_seconds", {"fn": name}, buckets=metrics.SECONDS_BUCKETS
-        ).observe(dt)
         if cost_enabled():
             try:
                 key = _sig_key(name, args, kw)
             except Exception:
                 key = None
             if key is not None and key not in _cost_seen:
-                # First call at this signature: the measured time is
-                # dominated by compilation — record the cost, skip the
-                # throughput join.
                 _cost_seen.add(key)
                 _record_cost(fn, name, args, kw)
-            else:
-                cost = _costs.get(name)
-                if cost and cost.get("flops") and dt > 0:
-                    achieved = cost["flops"] / dt
-                    metrics.gauge(
-                        "achieved_flops_per_s", {"fn": name}
-                    ).set(achieved)
-                    peak = peak_flops()
-                    if peak:
-                        metrics.gauge(
-                            "roofline_utilization", {"fn": name}
-                        ).set(achieved / peak)
         return out
 
     return wrapped

@@ -194,7 +194,7 @@ def test_instrument_jit_splits_compile_and_run():
     fn(x)
     fn(x)
     fn(jnp.arange(8.0))  # new shape: fresh lowering + compile
-    names = [s.name for s in obs.spans()]
+    names = [s.name for s in obs.spans() if s.name.startswith("double")]
     assert names == ["double[compile]", "double[run]", "double[compile]"]
 
 
@@ -414,3 +414,225 @@ def test_env_var_enables(monkeypatch):
     assert not state._env_enabled()
     monkeypatch.delenv("REPRO_OBS")
     assert not state._env_enabled()
+
+
+# ---------------------------------------------------------------------------
+# Build spans from JAX's compile events, device_wait, profiler annotations.
+# ---------------------------------------------------------------------------
+
+BUILD_SPANS = obs_trace.BUILD_SPANS
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+
+
+@pytest.fixture(scope="module")
+def tiny_batch():
+    """A 16-8-4 MLP on 8x8 parasitic tiles: two configs, two chunks."""
+    jax = pytest.importorskip("jax")
+    import jax.numpy as jnp
+
+    from repro.core.imac import IMACConfig
+
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
+    params = [
+        (0.3 * jax.random.normal(k1, (16, 8)), jnp.zeros(8)),
+        (0.3 * jax.random.normal(k2, (8, 4)), jnp.zeros(4)),
+    ]
+    x = jax.random.uniform(k3, (8, 16))
+    y = jnp.arange(8) % 4
+    cfgs = [IMACConfig(array_rows=8, array_cols=8, tech=t)
+            for t in ("MRAM", "PCM")]
+    return params, x, y, cfgs
+
+
+def _evaluate(batch):
+    from repro.core.evaluate import evaluate_batch
+
+    params, x, y, cfgs = batch
+    return evaluate_batch(params, x, y, cfgs, n_samples=8, chunk=4)
+
+
+def _self_times(spans):
+    child = {}
+    for s in spans:
+        if s.parent is not None:
+            child[s.parent] = child.get(s.parent, 0.0) + s.duration
+    return {s.sid: s.duration - child.get(s.sid, 0.0) for s in spans}
+
+
+def _repro_listeners():
+    from jax._src import monitoring
+
+    return [cb for cb in (monitoring.get_event_listeners()
+                          + monitoring.get_event_time_span_listeners())
+            if getattr(cb, "__module__", "").startswith("repro")]
+
+
+def test_import_registers_no_jax_listener():
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import repro, repro.obs, repro.core.evaluate\n"
+        "from jax._src import monitoring as m\n"
+        "cbs = m.get_event_listeners() + m.get_event_time_span_listeners()\n"
+        "print(sum(getattr(c, '__module__', '').startswith('repro') "
+        "for c in cbs))\n"
+    )
+    env = {k: v for k, v in os.environ.items() if k != "REPRO_OBS"}
+    out = subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                         capture_output=True, text=True, timeout=120)
+    assert out.stdout.strip().splitlines()[-1] == "0"
+
+
+def test_disabled_obs_has_no_listener_and_adds_no_block(tiny_batch,
+                                                        monkeypatch):
+    import jax
+
+    obs.enable()
+    assert len(_repro_listeners()) == 2
+    obs.disable()
+    assert _repro_listeners() == []
+
+    blocks = []
+    real = jax.block_until_ready
+
+    def counting(x):
+        blocks.append(1)
+        return real(x)
+
+    monkeypatch.setattr(jax, "block_until_ready", counting)
+    _evaluate(tiny_batch)
+    assert blocks == []
+    obs.enable()
+    _evaluate(tiny_batch)
+    assert len(blocks) == 1  # the device_wait span's, and no other
+    assert [s.name for s in obs.spans()].count("device_wait") == 1
+
+
+def test_obs_adds_no_jax_trace_to_evaluate_batch(tiny_batch):
+    from jax import monitoring
+
+    traces = []
+
+    def on_span(event, start, end, **kw):
+        if event == TRACE_EVENT:
+            traces.append(kw.get("fun_name"))
+
+    monitoring.register_event_time_span_listener(on_span)
+    try:
+        counts = {}
+        # One call each way first: module-level jitted helpers trace once
+        # per process, and the telemetry's helpers only with obs on.
+        for on in (False, True, False, True):
+            (obs.enable if on else obs.disable)()
+            traces.clear()
+            _evaluate(tiny_batch)
+            counts[on] = len(traces)
+    finally:
+        monitoring.unregister_event_time_span_listener(on_span)
+    assert counts[True] == counts[False] > 0
+    snap = obs.snapshot()
+    assert snap["jit_traces_total"]["series"][0]["value"] >= counts[True]
+
+
+def test_reused_jit_runs_without_build_spans():
+    jax = pytest.importorskip("jax")
+    import jax.numpy as jnp
+
+    def make():
+        def scale(v):
+            return jnp.sin(v) * 2.0
+
+        return scale
+
+    obs.enable()
+    x = jnp.arange(4.0)
+    reused = obs.instrument_jit(jax.jit(make()), "chunk")
+    reused(x)
+    obs.reset()
+    reused(x)
+    assert [s.name for s in obs.spans()] == ["chunk[run]"]
+    obs.reset()
+    obs.instrument_jit(jax.jit(make()), "chunk")(x)
+    spans = obs.spans()
+    assert any(s.name == "jit_trace" for s in spans)
+    assert spans[-1].name == "chunk[compile]"
+    top = spans[-1]
+    for s in spans[:-1]:
+        assert top.t_start <= s.t_start <= s.t_end <= top.t_end
+    roots = [s for s in spans if s.parent == top.sid]
+    assert [s.name for s in roots][:2] == ["jit_trace", "jit_lower"]
+    assert roots[0].attrs["fun_name"] == "scale"
+    names = [s.name for s in spans]
+    assert "jit_lower" in names
+    assert "executable_fetch" in names or "backend_compile" in names
+    snap = obs.snapshot()
+    assert (snap["jit_traces_total"]["series"][0]["value"]
+            == names.count("jit_trace"))
+
+
+def test_build_spans_nest_without_negative_self_time(tiny_batch):
+    obs.enable()
+    _evaluate(tiny_batch)
+    spans = obs.spans()
+    by_id = {s.sid: s for s in spans}
+    self_s = _self_times(spans)
+    chunks = [s for s in spans if s.name.startswith("solve_chunk")]
+    assert [s.name for s in chunks] == ["solve_chunk[compile]",
+                                        "solve_chunk[run]"]
+    build = [s for s in spans if s.name in BUILD_SPANS]
+    nested = [s for s in build if by_id[s.parent].name in BUILD_SPANS]
+    assert nested, "inner jits trace inside the outer trace"
+    for s in build:
+        # Float rounding of nested differences only.
+        assert self_s[s.sid] >= -1e-12, (s.name, self_s[s.sid])
+        parent = by_id[s.parent]
+        assert parent.t_start <= s.t_start <= s.t_end <= parent.t_end
+        assert s.depth == parent.depth + 1
+    under = [s for s in build
+             if by_id[s.parent].name == "solve_chunk[compile]"]
+    assert {s.name for s in under} >= {"jit_trace", "jit_lower"}
+    total = sum(self_s[s.sid] for s in build
+                if _ancestor(s, chunks[0].sid, by_id))
+    assert 0 < total <= chunks[0].duration
+    names = [s.name for s in spans if s.parent == by_id[chunks[0].parent].parent]
+    assert names.index("solve") < names.index("device_wait") < names.index(
+        "measure")
+
+
+def _ancestor(span, sid, by_id):
+    while span.parent is not None:
+        if span.parent == sid:
+            return True
+        span = by_id[span.parent]
+    return False
+
+
+def test_spans_are_profiler_annotations(tmp_path):
+    jax = pytest.importorskip("jax")
+    import glob
+    import os
+
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    obs.enable()
+    fn = obs.instrument_jit(jax.jit(lambda v: v + 1.0), "chunk_prof")
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.trace("outer_prof"):
+            fn(jnp.arange(4.0))
+
+        @obs.traced("decorated_prof")
+        def work():
+            return fn(jnp.arange(4.0))
+
+        jax.block_until_ready(work())
+    path = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                  "*.xplane.pb"))[-1]
+    host = [e.name for p in ProfileData.from_file(path).planes
+            if p.name.startswith("/host:")
+            for line in p.lines for e in line.events]
+    for name in ("outer_prof", "decorated_prof", "chunk_prof"):
+        assert name in host, name
+    assert not {"pass", "request"} & {s.name for s in obs.spans()}

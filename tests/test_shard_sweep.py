@@ -433,6 +433,9 @@ def test_sharded_run_sweep_tags_ledger(
     assert entries[0]["mesh_shape"] == "data1"
     assert "mesh=data1" in entries[0]["rows"][0]["derived"]
     assert entries[1]["mesh_shape"] is None
-    # The throughput gauge rode along in the embedded snapshot.
-    series = entries[0]["metrics"]["sweep_points_per_s"]["series"]
-    assert series[0]["value"] > 0
+    # The metrics snapshot rode along: the group program's build counters
+    # and the solve telemetry.
+    metrics = entries[0]["metrics"]
+    assert metrics["jit_traces_total"]["series"][0]["value"] > 0
+    assert metrics["solver_chunks_total"]["series"][0]["value"] > 0
+    assert "sweep_points_per_s" not in metrics
