@@ -15,7 +15,10 @@ arbitrary configuration lists into compatible batches via `structure_key`.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import functools
+import threading
 from typing import NamedTuple, Optional, Sequence
 
 import jax
@@ -24,10 +27,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
 from repro.obs import prof as obs_prof
+from repro.core.backends import get_backend
 from repro.core.digital import Params, mlp_forward
 from repro.core.imac import IMACConfig, build_plans, layer_latency, linear_forward
 from repro.core.mapping import MappedLayer, map_network
-from repro.core.partition import ordered_sum
+from repro.core.neurons import NeuronModel
+from repro.core.partition import PartitionPlan, ordered_sum
 from repro.core.solver import CircuitParams, SolveOptions, suggest_iters
 from repro.distributed.sweep import (
     MeshPlan,
@@ -210,6 +215,184 @@ def stack_mapped(
     return g_pos, g_neg, k
 
 
+@dataclasses.dataclass(frozen=True)
+class _GroupProgram:
+    """Everything a group program's body reads besides its array arguments.
+
+    `forward_all` reads its static inputs from this one value and from no
+    closure, so equal programs trace to the same computation: it is the
+    program cache's key. `solve_options.backend` holds the resolved
+    backend, never None, so a later change of $REPRO_SOLVER_BACKEND or of
+    the registry builds a new program.
+    """
+
+    plans: "tuple[PartitionPlan, ...]"
+    iters: "tuple[int, ...]"
+    tol: float
+    v_unit: float
+    neuron: NeuronModel
+    parasitics: bool
+    dtype: jnp.dtype
+    noise_per_config: bool
+    solve_options: SolveOptions
+
+
+def forward_all(prog: _GroupProgram, gp, gn, kk, sc, xb, nkey):
+    """Forward every stacked configuration over a chunk of samples.
+
+    The config axis is an ordinary leading batch axis: each layer is
+    ONE crossbar solve over (C, batch, tiles) with per-config
+    electrical scalars broadcast inside the solver — a single
+    while_loop, no per-lane masking.
+    """
+    n_layers = len(prog.plans)
+    a = xb  # (batch, F); becomes (C, batch, F) after the first layer.
+    keys = (
+        jax.random.split(nkey, n_layers)
+        if nkey is not None
+        else [None] * n_layers
+    )
+    powers, residuals, sweeps = [], [], []
+    for layer, plan in enumerate(prog.plans):
+        cp = CircuitParams(
+            r_row=sc["r_seg"],
+            r_col=sc["r_seg"],
+            r_source=sc["r_source"],
+            r_tia=sc["r_tia"],
+            gs_iters=prog.iters[layer],
+            omega=sc["omega"],
+            tol=prog.tol,
+        )
+        a, power, residual, _, swp = linear_forward(
+            gp[layer],
+            gn[layer],
+            kk[layer],
+            prog.v_unit,
+            plan,
+            cp,
+            prog.neuron,
+            a,
+            parasitics=prog.parasitics,
+            is_output=(layer == n_layers - 1),
+            solve_options=prog.solve_options,
+            noise_key=keys[layer],
+            read_noise_rel=sc["read_noise"],
+            noise_per_config=prog.noise_per_config,
+            dtype=prog.dtype,
+        )
+        powers.append(ordered_sum(power) / power.shape[-1])  # (C,)
+        residuals.append(residual)                # (C,)
+        sweeps.append(swp)                        # scalar per layer
+    pred = jnp.argmax(a, axis=-1)                 # (C, batch)
+    return (
+        pred,
+        jnp.stack(powers, axis=-1),
+        jnp.stack(residuals, axis=-1),
+        jnp.stack(sweeps),                        # (L,)
+    )
+
+
+def forward_nokey(prog: _GroupProgram, gp, gn, kk, sc, xb):
+    """`forward_all` without read noise (the sharded path's signature)."""
+    return forward_all(prog, gp, gn, kk, sc, xb, None)
+
+
+#: Group programs kept per process, least recently used evicted first.
+PROGRAM_CACHE_SIZE = 64
+
+_programs: "collections.OrderedDict[tuple, object]" = collections.OrderedDict()
+_programs_lock = threading.Lock()
+
+
+def clear_program_cache() -> None:
+    """Forget every cached group program (the next calls build anew)."""
+    with _programs_lock:
+        _programs.clear()
+
+
+def _bind(fn, prog: _GroupProgram):
+    """`fn` with its program bound, under fn's name (the jitted module's)."""
+    bound = functools.partial(fn, prog)
+    bound.__name__ = fn.__name__
+    return bound
+
+
+def _build_run_chunk(prog: _GroupProgram, noisy: bool, shard):
+    """The instrumented, jitted chunk solve of one group program.
+
+    `shard` is None, or (mesh, axis, stacked_specs) for the shard_map
+    path. prof.instrument_jit = the tracer's compile-vs-run span split
+    plus opt-in HLO cost analysis (hlo_flops / hlo_bytes_accessed).
+    """
+    if shard is None:
+        return obs_prof.instrument_jit(
+            jax.jit(_bind(forward_all, prog)), "solve_chunk"
+        )
+    mesh, axis, stacked_specs = shard
+    # Samples and the shared noise key replicate; the per-config outputs
+    # (pred, powers, residuals) concatenate back along the config axis,
+    # while the sweep counts — identical on every shard thanks to the
+    # global-pmax cond — come out replicated.
+    out_specs = (P(axis), P(axis), P(axis), P())
+    if noisy:
+        return obs_prof.instrument_jit(
+            jax.jit(jax.shard_map(
+                _bind(forward_all, prog),
+                mesh=mesh,
+                in_specs=stacked_specs + (P(), P()),
+                out_specs=out_specs,
+                check_vma=False,
+            )),
+            "solve_chunk",
+        )
+    inner = obs_prof.instrument_jit(
+        jax.jit(jax.shard_map(
+            _bind(forward_nokey, prog),
+            mesh=mesh,
+            in_specs=stacked_specs + (P(),),
+            out_specs=out_specs,
+            check_vma=False,
+        )),
+        "solve_chunk",
+    )
+
+    def run_chunk(gp, gn, kk, sc, xb, nk):
+        return inner(gp, gn, kk, sc, xb)
+
+    return run_chunk
+
+
+def _run_chunk_for(prog: _GroupProgram, noisy: bool, shard):
+    """The group program's chunk solve, reused when the process has it.
+
+    A reused jit object serves a repeat call from JAX's own dispatch
+    cache: no trace, no lowering, no executable fetch. Returns
+    (run_chunk, lookup), lookup "hit", "miss" or "bypass" (a key that
+    does not hash, e.g. an unhashable custom backend: built as before,
+    not kept).
+    """
+    key = (prog, noisy, None)
+    if shard is not None:
+        mesh, axis, stacked_specs = shard
+        leaves, tree = jax.tree_util.tree_flatten(stacked_specs)
+        key = (prog, noisy, (mesh, axis, tuple(leaves), tree))
+    try:
+        hash(key)
+    except TypeError:
+        return _build_run_chunk(prog, noisy, shard), "bypass"
+    with _programs_lock:
+        run_chunk = _programs.get(key)
+        if run_chunk is not None:
+            _programs.move_to_end(key)
+            return run_chunk, "hit"
+    run_chunk = _build_run_chunk(prog, noisy, shard)
+    with _programs_lock:
+        _programs[key] = run_chunk
+        while len(_programs) > PROGRAM_CACHE_SIZE:
+            _programs.popitem(last=False)
+    return run_chunk, "miss"
+
+
 def evaluate_batch(
     params: Params,
     x: jax.Array,
@@ -234,7 +417,9 @@ def evaluate_batch(
     conductance matrices and electrical scalars are stacked along a
     leading axis and the whole circuit simulation runs as one vmapped,
     jitted solve per sample chunk — one XLA compilation for the entire
-    group instead of one per configuration.
+    group instead of one per configuration. Later calls with the same
+    group program (see `_GroupProgram`) reuse the jitted solve from a
+    per-process cache and trace nothing (`clear_program_cache` empties it).
 
     When the configurations carry a `TransientSpec` (cfg.transient —
     identical across the batch by structure_key), the same stacked
@@ -456,6 +641,14 @@ def _evaluate_batch(
                 dtype=dtype, solve_options=solve_options,
             )
 
+    # The group program holds the backend itself (see _GroupProgram).
+    if solve_options is None:
+        solve_options = SolveOptions()
+    if solve_options.backend is None or isinstance(solve_options.backend, str):
+        solve_options = dataclasses.replace(
+            solve_options, backend=get_backend(solve_options.backend)
+        )
+
     # Sharded execution: pad the stacked config axis to a multiple of
     # the mesh axis (replicating entry 0 — trip-count-neutral, see
     # distributed/sweep.pad_stacked) and place every stacked tensor with
@@ -481,109 +674,28 @@ def _evaluate_batch(
             scal = {name: _stage(v) for name, v in scal.items()}
         # The tol early-exit must see the *global* residual max inside
         # shard_map; shard_axis routes a lax.pmax into the cond.
-        solve_options = dataclasses.replace(
-            solve_options if solve_options is not None else SolveOptions(),
-            shard_axis=s_axis,
-        )
-
-    def forward_all(gp, gn, kk, sc, xb, nkey):
-        """Forward every stacked configuration over a chunk of samples.
-
-        The config axis is an ordinary leading batch axis: each layer is
-        ONE crossbar solve over (C, batch, tiles) with per-config
-        electrical scalars broadcast inside the solver — a single
-        while_loop, no per-lane masking.
-        """
-        a = xb  # (batch, F); becomes (C, batch, F) after the first layer.
-        keys = (
-            jax.random.split(nkey, n_layers)
-            if nkey is not None
-            else [None] * n_layers
-        )
-        powers, residuals, sweeps = [], [], []
-        for layer, plan in enumerate(plans):
-            cp = CircuitParams(
-                r_row=sc["r_seg"],
-                r_col=sc["r_seg"],
-                r_source=sc["r_source"],
-                r_tia=sc["r_tia"],
-                gs_iters=iters[layer],
-                omega=sc["omega"],
-                tol=tol,
-            )
-            a, power, residual, _, swp = linear_forward(
-                gp[layer],
-                gn[layer],
-                kk[layer],
-                v_unit,
-                plan,
-                cp,
-                neuron,
-                a,
-                parasitics=parasitics,
-                is_output=(layer == n_layers - 1),
-                solve_options=solve_options,
-                noise_key=keys[layer],
-                read_noise_rel=sc["read_noise"],
-                noise_per_config=noise_per_config,
-                dtype=dtype,
-            )
-            powers.append(ordered_sum(power) / power.shape[-1])  # (C,)
-            residuals.append(residual)                # (C,)
-            sweeps.append(swp)                        # scalar per layer
-        pred = jnp.argmax(a, axis=-1)                 # (C, batch)
-        return (
-            pred,
-            jnp.stack(powers, axis=-1),
-            jnp.stack(residuals, axis=-1),
-            jnp.stack(sweeps),                        # (L,)
-        )
+        solve_options = dataclasses.replace(solve_options, shard_axis=s_axis)
 
     obs_prof.sample_memory("stamp")
 
-    # prof.instrument_jit = the tracer's compile-vs-run span split plus
-    # opt-in HLO cost analysis (hlo_flops / hlo_bytes_accessed).
+    prog = _GroupProgram(
+        plans=tuple(plans),
+        iters=tuple(iters),
+        tol=tol,
+        v_unit=v_unit,
+        neuron=neuron,
+        parasitics=parasitics,
+        dtype=jnp.dtype(dtype),
+        noise_per_config=noise_per_config,
+        solve_options=solve_options,
+    )
+    shard_specs = None
     if shard is not None:
-        stacked_specs = jax.tree_util.tree_map(
+        shard_specs = (s_mesh, s_axis, jax.tree_util.tree_map(
             lambda t: stacked_spec(t, s_mesh, s_axis), (g_pos, g_neg, k, scal)
-        )
-        # Samples and the shared noise key replicate; the per-config
-        # outputs (pred, powers, residuals) concatenate back along the
-        # config axis, while the sweep counts — identical on every
-        # shard thanks to the global-pmax cond — come out replicated.
-        out_specs = (P(s_axis), P(s_axis), P(s_axis), P())
-        if noise_key is None:
-            def forward_nokey(gp, gn, kk, sc, xb):
-                return forward_all(gp, gn, kk, sc, xb, None)
-
-            inner = obs_prof.instrument_jit(
-                jax.jit(jax.shard_map(
-                    forward_nokey,
-                    mesh=s_mesh,
-                    in_specs=stacked_specs + (P(),),
-                    out_specs=out_specs,
-                    check_vma=False,
-                )),
-                "solve_chunk",
-            )
-
-            def run_chunk(gp, gn, kk, sc, xb, nk):
-                return inner(gp, gn, kk, sc, xb)
-        else:
-            run_chunk = obs_prof.instrument_jit(
-                jax.jit(jax.shard_map(
-                    forward_all,
-                    mesh=s_mesh,
-                    in_specs=stacked_specs + (P(), P()),
-                    out_specs=out_specs,
-                    check_vma=False,
-                )),
-                "solve_chunk",
-            )
-    else:
-        run_chunk = obs_prof.instrument_jit(
-            jax.jit(forward_all), "solve_chunk"
-        )
+        ))
+    run_chunk, lookup = _run_chunk_for(prog, noise_key is not None, shard_specs)
+    obs.counter("program_cache_lookups_total", {"result": lookup}).inc()
 
     n_chunks = (n + chunk - 1) // chunk
     keys = (
@@ -800,8 +912,9 @@ def sweep(
     """Design-space sweep, one configuration at a time (the paper's
     Tables III/IV are sweeps over partitioning / device technology).
 
-    This is the reference per-config loop: every configuration re-traces
-    and re-compiles its own solve. Prefer repro.explore.run_sweep, which
+    This is the reference per-config loop: every configuration runs its
+    own solve (configurations of one structure share a cached jitted
+    program). Prefer repro.explore.run_sweep, which
     groups structurally-compatible configurations into single vmapped
     solves and memoizes results on disk.
     """

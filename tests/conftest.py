@@ -47,3 +47,14 @@ def trained_tiny_mlp():
     acc = accuracy(params, xte, yte)
     assert acc > 0.9, f"reference MLP failed to train: {acc}"
     return params, xte, yte
+
+
+@pytest.fixture(autouse=True)
+def _cold_program_cache():
+    """Each test starts with no cached group program, as in a new process,
+    so one test's warm programs never change another's trace counts or
+    compile/run spans."""
+    from repro.core.evaluate import clear_program_cache
+
+    clear_program_cache()
+    yield

@@ -513,6 +513,8 @@ def test_disabled_obs_has_no_listener_and_adds_no_block(tiny_batch,
 def test_obs_adds_no_jax_trace_to_evaluate_batch(tiny_batch):
     from jax import monitoring
 
+    from repro.core.evaluate import clear_program_cache
+
     traces = []
 
     def on_span(event, start, end, **kw):
@@ -521,19 +523,26 @@ def test_obs_adds_no_jax_trace_to_evaluate_batch(tiny_batch):
 
     monitoring.register_event_time_span_listener(on_span)
     try:
-        counts = {}
-        # One call each way first: module-level jitted helpers trace once
+        first, second = {}, {}
+        # One round each way first: module-level jitted helpers trace once
         # per process, and the telemetry's helpers only with obs on.
         for on in (False, True, False, True):
             (obs.enable if on else obs.disable)()
+            clear_program_cache()
             traces.clear()
             _evaluate(tiny_batch)
-            counts[on] = len(traces)
+            first[on] = len(traces)
+            traces.clear()
+            _evaluate(tiny_batch)
+            second[on] = len(traces)
     finally:
         monitoring.unregister_event_time_span_listener(on_span)
-    assert counts[True] == counts[False] > 0
+    # A new group program traces the same with obs on and off; the same
+    # call again reuses it and traces nothing.
+    assert first[True] == first[False] > 0
+    assert second[True] == second[False] == 0
     snap = obs.snapshot()
-    assert snap["jit_traces_total"]["series"][0]["value"] >= counts[True]
+    assert snap["jit_traces_total"]["series"][0]["value"] >= first[True]
 
 
 def test_reused_jit_runs_without_build_spans():
