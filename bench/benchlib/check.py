@@ -61,12 +61,8 @@ def numbers_for(result, ref, n_samples: int) -> dict:
     }
 
 
-def compare(traffic, calls, seed: int, results_of=None) -> dict:
-    """Worst value of each number over the sampled calls and points.
-
-    `results_of(call)` gives the results to judge (default: the call's own),
-    which lets the control put other results in the program's place.
-    """
+def compare(traffic, calls, seed: int) -> dict:
+    """Worst value of each number over the sampled calls and points."""
     params = [(np.asarray(w, np.float64), np.asarray(b, np.float64))
               for w, b in traffic.params]
     worst = {k: 0.0 for k in NUMBERS}
@@ -77,8 +73,7 @@ def compare(traffic, calls, seed: int, results_of=None) -> dict:
         ref = reference.evaluate_point(
             traffic.cfg, {"tech": point.tech, "partitioning": point.partitioning},
             params, xs, ys, parasitics=traffic.parasitics)
-        results = results_of(call) if results_of else call.results
-        got = results[i] if i < len(results) else None
+        got = call.results[i] if i < len(call.results) else None
         nums = numbers_for(got, ref, traffic.n_samples)
         for k, v in nums.items():
             worst[k] = max(worst[k], v)
@@ -87,11 +82,3 @@ def compare(traffic, calls, seed: int, results_of=None) -> dict:
     worst["checked_points"] = checked
     worst["per_point"] = per_point
     return worst
-
-
-def verdict(worst: dict, limits: dict) -> "tuple[bool, dict]":
-    """(correct, {number: {value, limit}})."""
-    shown = {k: {"value": worst[k], "limit": limits[k]} for k in NUMBERS}
-    ok = worst.get("checked_points", 0) > 0 and all(
-        v["value"] <= v["limit"] for v in shown.values())
-    return ok, shown

@@ -20,17 +20,24 @@ def latencies_ms(ctx) -> np.ndarray:
     return np.asarray([c.latency_s for c in ctx.calls]) * 1e3
 
 
-def host_ms_per_point(ctx):
-    """Self time of the engine's and evaluation's host spans, per point."""
+def self_ms_per_point(ctx, names):
+    """Self time of the program's spans named `names`, per point."""
     if not ctx.spans or not points_done(ctx):
         return None
     child = {}
     for sp in ctx.spans:
         if sp.parent is not None:
             child[sp.parent] = child.get(sp.parent, 0.0) + sp.duration
-    self_s = sum(sp.duration - child.get(sp.sid, 0.0) for sp in ctx.spans
-                 if sp.name in ctx.host_spans)
+    mine = [sp for sp in ctx.spans if sp.name in names]
+    if not mine:
+        return None
+    self_s = sum(sp.duration - child.get(sp.sid, 0.0) for sp in mine)
     return 1e3 * self_s / points_done(ctx)
+
+
+def host_ms_per_point(ctx):
+    """Self time of the engine's and evaluation's host spans, per point."""
+    return self_ms_per_point(ctx, ctx.host_spans)
 
 
 def sweeps_per_solve(ctx):

@@ -24,6 +24,11 @@ digital weights it rebuilds, in NumPy float64:
 
 The ideal (no wire) crossbar replaces the grid by I = G^T V and its power
 by sum (G+ + G-) V^2.
+
+`forward` takes the conductances themselves, so that a Monte-Carlo trial
+(`benchlib.trials`) is solved with its own drawn devices, one
+factorisation per trial and tile, and optional read noise on the sensed
+differential currents, I += rel * max(|I|, 1e-12) * draw.
 """
 from __future__ import annotations
 
@@ -147,15 +152,23 @@ class Outcome:
 def evaluate_point(cfg, point, params, x, y, *, parasitics=True) -> Outcome:
     """Forward `x` through the deployment `point` of configuration `cfg`."""
     tech = cfg["technologies"][point["tech"]]
+    layers = [map_layer(w, b, tech) for w, b in params]
+    return forward(cfg, point["partitioning"], layers, x, y, parasitics=parasitics)
+
+
+def forward(cfg, partitioning, layers, x, y, *, parasitics=True,
+            read_noise=None) -> Outcome:
+    """Forward `x` through conductances `layers`, per layer (G+, G-, k) as
+    `map_layer` gives them, tiled by `partitioning`. `read_noise`: None, or
+    per layer (rel, draws (samples, fan_out))."""
     vdd = cfg["vdd"]
     neuron = cfg["neuron"]
     z_lim = vdd / neuron["z_volt"]
     r_seg = r_segment(cfg["interconnect"])
-    plans = plan_layers(cfg["topology"], point["partitioning"])
+    plans = plan_layers(cfg["topology"], partitioning)
     a = np.asarray(x, np.float64)
     powers, dev_powers = [], []
-    for layer, ((w, b), plan) in enumerate(zip(params, plans)):
-        g_pos, g_neg, k = map_layer(w, b, tech)
+    for layer, ((g_pos, g_neg, k), plan) in enumerate(zip(layers, plans)):
         v = np.concatenate([a, np.ones((a.shape[0], 1))], axis=1) * vdd
         if parasitics:
             v_pad = np.zeros((v.shape[0], plan.hp * plan.rows))
@@ -174,8 +187,11 @@ def evaluate_point(cfg, point, params, x, y, *, parasitics=True) -> Outcome:
         else:
             i_diff = v @ (g_pos - g_neg)
             p_dev = (v ** 2) @ (g_pos + g_neg).sum(axis=1)
+        if read_noise is not None:
+            rel, draws = read_noise[layer]
+            i_diff = i_diff + rel * np.maximum(np.abs(i_diff), 1e-12) * draws
         z = np.clip(i_diff / (k * vdd), -z_lim, z_lim)
-        a = z if layer == len(params) - 1 else 1.0 / (1.0 + np.exp(-z))
+        a = z if layer == len(layers) - 1 else 1.0 / (1.0 + np.exp(-z))
         dev_powers.append(float(np.mean(p_dev)))
         powers.append(dev_powers[-1] + interface_power(plan, neuron))
     return Outcome(

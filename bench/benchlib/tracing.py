@@ -3,11 +3,12 @@
 Busy time is the union of the intervals in which an operation runs on a
 device ("XLA Ops" line of each device plane), clipped to the traced
 window. The window runs from the start of the first harness annotation
-(`pass` or `request`) to the end of the last. Idle time is the rest of
-the window; each idle stretch is cut where the host's activity changes
-and each piece is named by it: the innermost program span then open (the
-program's `repro.obs` spans, moved onto the trace's clock), else the
-harness annotation, else "outside".
+(the traffic's label around each call: `pass`, `request`, `study`) to
+the end of the last. Idle time is the rest of the window; each idle
+stretch is cut where the host's activity changes and each piece is
+named by it: the innermost program span then open (the program's
+`repro.obs` spans, moved onto the trace's clock), else the harness
+annotation, else "outside".
 """
 from __future__ import annotations
 
@@ -15,7 +16,6 @@ import glob
 import os
 from typing import Iterable, Optional, Sequence
 
-ANNOTATIONS = ("pass", "request")
 OPS_LINE = "XLA Ops"
 
 
@@ -75,7 +75,8 @@ class Trace:
         self.annotations = sorted(annotations, key=lambda a: a[1])
 
     @classmethod
-    def load(cls, path: str) -> "Trace":
+    def load(cls, path: str, labels: "Sequence[str]") -> "Trace":
+        """Device ops and the host annotations named `labels` of a trace."""
         from jax.profiler import ProfileData
 
         pd = ProfileData.from_file(path)
@@ -91,7 +92,7 @@ class Trace:
             elif plane.name.startswith("/host:"):
                 for line in plane.lines:
                     for e in line.events:
-                        if e.name in ANNOTATIONS:
+                        if e.name in labels:
                             annotations.append((e.name, e.start_ns, e.end_ns))
         return cls(device_ops, annotations)
 

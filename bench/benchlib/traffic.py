@@ -1,7 +1,13 @@
-"""The one traffic generator: reads a mix's parameters and drives the program.
+"""The digit-MLP traffic generator: reads a mix's parameters and drives the program.
 
-Two kinds of mix, both through `repro.explore.run_sweep` with no result
-cache, on the program's default solver path:
+A mix's `kind` names its module in `bench/kinds/` (`<kind>.py`), which
+makes the run's inputs and weights, builds the object that drives the
+calls, and owns the comparison and the count of failed results (see
+`bench/run.py`). The `sweep` and `loop` kinds drive this generator; the
+`variability` kind reuses its warm-up, window and inputs.
+
+Two kinds of mix here, both through `repro.explore.run_sweep` with no
+result cache, on the program's default solver path:
 
   sweep  every design point of the configuration in one `run_sweep` call
          (a pass) over the next `n_samples` test inputs of the seeded
@@ -19,6 +25,7 @@ a traced run.
 from __future__ import annotations
 
 import dataclasses
+import math
 import time
 
 import jax
@@ -132,3 +139,18 @@ class Traffic:
         xs = np.asarray(self.x_pool[call.offset:call.offset + self.n_samples])
         ys = np.asarray(self.y_pool[call.offset:call.offset + self.n_samples])
         return xs, ys
+
+
+def on_digits(cfg: dict, mix: dict, seed: int, cls=Traffic, **kw):
+    """(traffic, notes): a `cls` over the seed's digit pool and trained MLP
+    (`benchlib.data`); `kw` goes to its constructor."""
+    params, x_pool, y_pool, digital_acc = data.make_workload(seed, cfg)
+    return (cls(cfg, mix, seed, params, x_pool, y_pool, **kw),
+            {"digital_accuracy": digital_acc})
+
+
+def failed(calls) -> int:
+    """Results (design points, or Monte-Carlo trials) whose accuracy or
+    power is not finite."""
+    return sum(1 for c in calls for r in c.results
+               if not math.isfinite(r.avg_power + r.accuracy))

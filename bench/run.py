@@ -7,17 +7,39 @@ The cell is looked up by name in BENCHMARK.json at the root of the
 checkout. Its configuration (`bench/configs/<config>.json`), traffic mix
 (`bench/traffic/<traffic>.json`), comparison limits
 (`bench/limits/<cell>.json`) and the reader of each metric
-(`bench/metrics/<metric>.py`) are found by name.
+(`bench/metrics/<metric>.py`) are found by name, and so is the mix's
+kind (`bench/kinds/<kind>.py`, from the mix's `kind`). A kind module
+provides:
 
-Set-up makes the inputs and trains the weights from the seed on the
-device, then warms up every program the traffic uses. The window then
-runs the traffic for `--seconds` (whole calls only). With `--trace 1`
-the window runs with the program's spans on and under the JAX profiler,
-and the result carries the cell's per-layer metrics; with `--trace 0` it
-carries the end-to-end metrics. After the window a sample of its results
-is compared with the float64 reference. The last line of standard output
-is one JSON object; the numbers compared, with their limits, end it and
-end standard error.
+  build(cfg, mix, seed, control=None)
+            -> (traffic, notes): the run's inputs and weights made from
+               the seed, and the object that drives the program's calls;
+               `control` names one of CONTROLS, a lower-precision or
+               otherwise broken computation in the program's place (for
+               `bench/control.py` only); `notes` is logged with set-up
+  CONTROLS  the control names `build` takes
+  NUMBERS   the names of the numbers `compare` returns, each with a limit
+  compare(traffic, calls, seed)
+            -> {number: worst value, "checked_points": n, "per_point": [...]}
+  failed(calls)
+            -> the count of results that are not finite
+
+The traffic has `label` (the profiler annotation around each call),
+`n_samples` (inputs per evaluation unit), `parasitics`, `warm_up()`,
+`window(seconds, label, on_call=None)` -> (calls, window_s) and
+`inputs(call)`. Each call has `points` (one entry per evaluation unit:
+a design point, or a Monte-Carlo trial, with its structure `group` and
+`partitioning`), `t_sent`, `t_done` and `latency_s`.
+
+Set-up makes the inputs and weights from the seed on the device, then
+warms up every program the traffic uses. The window then runs the
+traffic for `--seconds` (whole calls only). With `--trace 1` the window
+runs with the program's spans on and under the JAX profiler, and the
+result carries the cell's per-layer metrics; with `--trace 0` it carries
+the end-to-end metrics. After the window the kind compares a sample of
+its results with the float64 reference. The last line of standard
+output is one JSON object; the numbers compared, with their limits, end
+it and end standard error.
 
 Without a TPU, or with fewer chips than the cell needs, it prints no
 result and exits 3.
@@ -32,7 +54,6 @@ import argparse  # noqa: E402
 import collections  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
-import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -41,6 +62,7 @@ from pathlib import Path  # noqa: E402
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
+KINDS = BENCH / "kinds"
 sys.path.insert(0, str(BENCH))
 
 #: Program spans whose self time is host work of the engine and evaluation.
@@ -72,12 +94,34 @@ def load_cell(workload: str) -> dict:
     }
 
 
-def reader(name: str):
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"metric_{name}", path)
+def load_module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod  # dataclasses look their module up
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(name: str):
+    return load_module(BENCH / "metrics" / f"{name}.py", f"metric_{name}").read
+
+
+def load_kind(name: str):
+    """The kind module of a traffic mix, `bench/kinds/<name>.py`."""
+    path = KINDS / f"{name}.py"
+    if not path.is_file():
+        known = sorted(p.stem for p in KINDS.glob("*.py"))
+        raise SystemExit(f"unknown traffic kind {name!r} (no {path}); known: {known}")
+    return load_module(path, f"kind_{name}")
+
+
+def verdict(worst: dict, limits: dict, numbers) -> "tuple[bool, dict]":
+    """(correct, {number: {value, limit}}): every number within its limit,
+    and something was checked."""
+    shown = {k: {"value": worst[k], "limit": limits[k]} for k in numbers}
+    ok = worst.get("checked_points", 0) > 0 and all(
+        v["value"] <= v["limit"] for v in shown.values())
+    return ok, shown
 
 
 def compile_counter():
@@ -151,14 +195,13 @@ def execute(args, spec, devices) -> int:
 
     cell, cfg, mix = spec["cell"], spec["config"], spec["mix"]
     import_program()
+    kind = load_kind(mix["kind"])
     cache_dir = use_compile_cache()
     compiles = compile_counter()
 
-    from benchlib import check, data, tracing
-    from benchlib.traffic import Traffic
+    from benchlib import tracing
 
-    params, x_pool, y_pool, digital_acc = data.make_workload(args.seed, cfg)
-    traffic = Traffic(cfg, mix, args.seed, params, x_pool, y_pool)
+    traffic, notes = kind.build(cfg, mix, args.seed)
     if args.trace:
         from repro import obs
 
@@ -166,13 +209,16 @@ def execute(args, spec, devices) -> int:
     traffic.warm_up()
     setup_s = time.perf_counter() - T_START
     before = dict(compiles)
-    say(f"bench: setup_s={setup_s} digital_accuracy={digital_acc} "
+    say(f"bench: setup_s={setup_s} {' '.join(f'{k}={v}' for k, v in notes.items())} "
         f"compile_cache={cache_dir} compiles_in_setup={before}")
 
-    spans, snapshot, host_marks = [], {}, []
+    spans, snapshot, host_marks, registered = [], {}, [], set()
     if args.trace:
         logdir = BENCH / ".out" / "trace"
         shutil.rmtree(logdir, ignore_errors=True)
+        # The metrics the program registered in set-up: a counter that the
+        # window leaves at 0 reads 0, not "absent", where the program has it.
+        registered = set(obs.snapshot())
         obs.reset()
         seconds = min(args.seconds, float(mix.get("trace_seconds", args.seconds)))
         options = jax.profiler.ProfileOptions()
@@ -183,7 +229,8 @@ def execute(args, spec, devices) -> int:
                 on_call=lambda c: host_marks.append((traffic.label, c.t_sent * 1e9)))
         spans, snapshot = obs.spans(), obs.snapshot()
         obs.disable()
-        trace = tracing.Trace.load(tracing.find_xplane(str(logdir)))
+        trace = tracing.Trace.load(tracing.find_xplane(str(logdir)),
+                                   labels=(traffic.label,))
         offset = trace.clock_offset(host_marks)
         summary = trace.summary(tracing.spans_on_trace(spans, offset))
         shutil.rmtree(logdir, ignore_errors=True)
@@ -204,8 +251,8 @@ def execute(args, spec, devices) -> int:
     ctx = types.SimpleNamespace(
         config=cfg, traffic=traffic, calls=calls, window_s=window_s,
         setup_s=setup_s, trace=summary, spans=spans, snapshot=snapshot,
-        host_spans=HOST_SPANS, device_kind=devices[0].device_kind,
-        chips=cell["chips"],
+        registered=registered | set(snapshot), host_spans=HOST_SPANS,
+        device_kind=devices[0].device_kind, chips=cell["chips"],
     )
     wanted = spec["per_layer"] if args.trace else spec["end_to_end"]
     metrics = {}
@@ -216,11 +263,10 @@ def execute(args, spec, devices) -> int:
 
     attempted = sum(len(c.points) for c in calls)
     t_check = time.perf_counter()
-    worst = check.compare(traffic, calls, args.seed)
+    worst = kind.compare(traffic, calls, args.seed)
     check_s = time.perf_counter() - t_check
-    correct, shown = check.verdict(worst, spec["limits"])
-    failed = sum(1 for c in calls for r in c.results
-                 if not math.isfinite(r.avg_power + r.accuracy))
+    correct, shown = verdict(worst, spec["limits"], kind.NUMBERS)
+    failed = kind.failed(calls)
 
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
               "count": len(devices), "memory_peak_bytes": peak}

@@ -29,6 +29,15 @@ def small_spec(workload: str, n_samples: int = 4, groups=None) -> dict:
     return spec
 
 
+def small_study(n_samples: int = 8, trials: int = 4, **mix) -> dict:
+    """The Monte-Carlo cell at a CPU size: two solve chunks, every trial
+    checked."""
+    spec = small_spec("tableIV-mram-mc64", n_samples)
+    spec["mix"].update(trials=trials, chunk=n_samples // 2, check_trials=trials,
+                       **mix)
+    return spec
+
+
 def run_small(spec: dict, capsys, seed: int = 2**31 + 5, seconds: float = 0.01,
               trace: int = 0) -> dict:
     """Drive bench/run.py's `execute` on the CPU; return its result line."""

@@ -46,7 +46,7 @@ def main() -> int:
     out = HERE / "fixtures" / "loop_trace.xplane.pb"
     out.parent.mkdir(exist_ok=True)
     shutil.copy(tracing.find_xplane(str(logdir)), out)
-    print(tracing.Trace.load(str(out)).summary())
+    print(tracing.Trace.load(str(out), labels=("request",)).summary())
     shutil.rmtree(logdir, ignore_errors=True)
     return 0
 

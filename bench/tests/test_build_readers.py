@@ -1,14 +1,13 @@
-"""The readers of the program's build spans, trace counter and device wait,
-on a synthetic run context."""
+"""The readers of the program's trace counter, device wait and trial
+sampling, on a synthetic run context."""
 import types
 
 import pytest
 
 import run
 
-NEW = ("program_build_ms_per_point.batch", "program_build_ms_per_point.loop",
-       "jit_traces_per_point.batch", "jit_traces_per_point.loop",
-       "device_wait_ms_per_point.batch")
+NEW = ("jit_traces_per_point.batch", "jit_traces_per_point.loop",
+       "device_wait_ms_per_point.batch", "trial_sampling_ms_per_trial")
 
 
 def span(sid, name, parent, t0, t1):
@@ -16,10 +15,14 @@ def span(sid, name, parent, t0, t1):
                                  t_start=t0, t_end=t1, duration=t1 - t0)
 
 
-def ctx(spans, snapshot, points=4):
+def ctx(spans, snapshot, points=4, registered=None):
+    """Two calls of `points` points; `registered`: the metric names the
+    program registered in set-up and window (default: the window's)."""
     call = types.SimpleNamespace(points=[object()] * points)
+    if registered is None:
+        registered = set(snapshot)
     return types.SimpleNamespace(spans=spans, snapshot=snapshot,
-                                 calls=[call, call])
+                                 registered=registered, calls=[call, call])
 
 
 def traced_run():
@@ -43,18 +46,31 @@ def traced_run():
     return ctx(spans, snapshot)
 
 
-@pytest.mark.parametrize("name", ["program_build_ms_per_point.batch",
-                                  "program_build_ms_per_point.loop"])
-def test_build_time_is_self_time_of_build_spans(name):
-    # 1.5 s outer trace + 2 s lowering + 1 s fetch over 8 points: the
-    # nested traces count once, inside their parents.
-    assert run.reader(name)(traced_run()) == pytest.approx(1e3 * 4.5 / 8)
-
-
 @pytest.mark.parametrize("name", ["jit_traces_per_point.batch",
                                   "jit_traces_per_point.loop"])
 def test_traces_per_point_from_the_counter(name):
     assert run.reader(name)(traced_run()) == pytest.approx(4.0 / 8)
+
+
+@pytest.mark.parametrize("name", ["jit_traces_per_point.batch",
+                                  "jit_traces_per_point.loop"])
+def test_a_warm_window_reads_zero_traces(name):
+    # The program registered the counter in set-up and traced nothing in
+    # the window: the snapshot taken after the window has no series.
+    spans = [span(1, "evaluate_batch", None, 0.0, 10.0)]
+    warm = ctx(spans, {}, registered={"jit_traces_total", "solver_sweeps"})
+    assert run.reader(name)(warm) == 0.0
+
+
+def test_trial_sampling_is_self_time_per_trial():
+    # Two studies of 4 trials; sample_trials holds a nested build span.
+    spans = [span(1, "run_variability", None, 0.0, 10.0),
+             span(2, "sample_trials", 1, 0.0, 2.0),
+             span(3, "jit_trace", 2, 0.5, 1.0),
+             span(4, "run_variability", None, 10.0, 20.0),
+             span(5, "sample_trials", 4, 10.0, 11.0)]
+    assert run.reader("trial_sampling_ms_per_trial")(ctx(spans, {})) == (
+        pytest.approx(1e3 * 2.5 / 8))
 
 
 def test_device_wait_per_point():

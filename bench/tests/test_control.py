@@ -1,45 +1,46 @@
-"""The control comes out not correct: the program's bfloat16 path, one
-precision below the float32 the configuration states, fails the
-comparison's limits (run here at a test size; `bench/control.py` reads
-it on the chip at the cell's size)."""
+"""The controls come out not correct while the program comes out correct
+(run here at a test size; `bench/control.py` reads them on the chip at the
+cell's size): the program's bfloat16 path, one precision below the float32
+the configuration states; in the Monte-Carlo cell also a checked trial
+drawn with another key."""
 import jax
-import jax.numpy as jnp
 import pytest
 
-from benchlib import check, data
-from benchlib.traffic import Traffic
+import run
+from conftest import small_study
+
+
+def readings(kind, spec, seed, control, n_calls=1):
+    traffic, _ = kind.build(spec["config"], spec["mix"], seed, control=control)
+    calls = [c for _ in range(n_calls) for c in traffic.window(0, traffic.label)[0]]
+    worst = kind.compare(traffic, calls, seed)
+    return run.verdict(worst, spec["limits"], kind.NUMBERS)[0]
 
 
 @pytest.mark.parametrize("workload,groups", [
     ("tableIV-sweep", None), ("grid-sweep", ["32x32", "128x128"])])
 def test_bfloat16_control_fails(small, workload, groups):
     spec = small(workload, 4, groups)
+    kind = run.load_kind(spec["mix"]["kind"])
     seed = 2**31 + 21
-    params, x_pool, y_pool, _ = data.make_workload(seed, spec["config"])
-    worst = {}
-    for dtype in (None, jnp.bfloat16):
-        traffic = Traffic(spec["config"], spec["mix"], seed, params, x_pool,
-                        y_pool, dtype=dtype)
-        calls = [traffic.call(traffic.next_points(), traffic.label)]
-        worst[dtype] = check.compare(traffic, calls, seed)
-    assert check.verdict(worst[None], spec["limits"])[0] is True
-    assert check.verdict(worst[jnp.bfloat16], spec["limits"])[0] is False
+    assert readings(kind, spec, seed, None) is True
+    assert readings(kind, spec, seed, "control_bf16") is False
+
+
+@pytest.mark.parametrize("control", [None, "control_bf16", "control_wrong_key"])
+def test_study_controls_fail(control):
+    spec = small_study()
+    kind = run.load_kind("variability")
+    assert readings(kind, spec, 2**31 + 23, control) is (control is None)
 
 
 @pytest.mark.skipif(jax.devices()[0].platform != "tpu",
                     reason="the CPU computes Precision.HIGH in full float32")
 def test_high_precision_control_fails_on_the_ideal_path(small):
     """Run on the chip: JAX_PLATFORMS=tpu python -m pytest bench/tests/test_control.py"""
-    import control
-
     spec = small("grid-screen-loop", 256)
     spec["config"]["test_set_size"] = 2048
+    kind = run.load_kind("loop")
     seed = 2**31 + 22
-    params, x_pool, y_pool, _ = data.make_workload(seed, spec["config"])
-    traffic = Traffic(spec["config"], spec["mix"], seed, params, x_pool, y_pool)
-    calls = [traffic.call(traffic.next_points(), traffic.label) for _ in range(8)]
-    sound = check.compare(traffic, calls, seed)
-    high = check.compare(traffic, calls, seed, results_of=lambda c: control.ideal_results(
-        spec["config"], traffic, c, jax.lax.Precision.HIGH))
-    assert check.verdict(sound, spec["limits"])[0] is True
-    assert check.verdict(high, spec["limits"])[0] is False
+    assert readings(kind, spec, seed, None, n_calls=8) is True
+    assert readings(kind, spec, seed, "control_high", n_calls=8) is False

@@ -14,6 +14,17 @@ program below the harness, and reads `correct` from the result line:
               stacked group program) has its output-layer power changed;
   no_exchange (four devices) the sharded sweep reads every chip's results
               from chip 0: the gather of results between chips is left out.
+
+The Monte-Carlo cell (`tableIV-mram-mc64`) takes the first three in its
+solve, and its own:
+
+  wrong_key   the first checked trial of every study is drawn with another
+              key;
+  no_stuck    the stuck-at faults are left out;
+  half_trials the report summarises only the first half of the trials;
+  no_read_noise  the read noise is left out, at the cell's own 1%
+              (`test_study_read_noise_follows_the_program`): it moves a
+              trial's power through the activations it changes.
 """
 import json
 import os
@@ -22,9 +33,11 @@ import sys
 import textwrap
 from pathlib import Path
 
+import jax
 import pytest
 
-from conftest import run_small
+import run
+from conftest import run_small, small_study
 
 BENCH = Path(__file__).resolve().parents[1]
 
@@ -132,3 +145,71 @@ def test_no_exchange_between_chips_is_caught(fault):
     assert proc.returncode == 0, proc.stderr[-3000:]
     result = json.loads(proc.stdout.strip().splitlines()[-1])
     assert result["correct"] is (not fault)
+
+
+def wrong_key(monkeypatch, spec, seed):
+    import repro.variability.engine as mc
+
+    mix = spec["mix"]
+    t = run.load_kind("variability").checked_trials(
+        seed, mix["trials"], mix["check_trials"])[0]
+    real = mc.trial_keys
+
+    def trial_keys(vspec):
+        keys = real(vspec)
+        return keys.at[t].set(jax.random.fold_in(keys[t], 1))
+    monkeypatch.setattr(mc, "trial_keys", trial_keys)
+
+
+def no_stuck(monkeypatch, spec, seed):
+    import repro.variability.engine as mc
+
+    monkeypatch.setattr(mc, "apply_stuck_faults", lambda g, *_: g)
+
+
+def half_trials(monkeypatch, spec, seed):
+    import repro.variability.engine as mc
+
+    real = mc.summarize
+    monkeypatch.setattr(mc, "summarize",
+                        lambda results, **kw: real(results[: len(results) // 2], **kw))
+
+
+def no_read_noise(real):
+    def evaluate_batch(*args, noise_key=None, **kw):
+        return real(*args, noise_key=None, **kw)
+    return evaluate_batch
+
+
+SEED = 2**31 + 5
+
+
+def test_study_sound_run_is_correct(capsys):
+    result = run_small(small_study(), capsys, seed=SEED)
+    assert result["correct"] is True
+    assert result["attempted"] == 4 and result["failed"] == 0
+
+
+@pytest.mark.parametrize("fault", [half_batch, altered, miscounted])
+def test_study_fault_in_the_solve_is_caught(capsys, monkeypatch, fault):
+    import repro.variability.engine as mc
+
+    monkeypatch.setattr(mc, "evaluate_batch", fault(mc.evaluate_batch))
+    assert run_small(small_study(), capsys, seed=SEED)["correct"] is False
+
+
+@pytest.mark.parametrize("fault", [wrong_key, no_stuck, half_trials])
+def test_study_fault_in_the_trials_is_caught(capsys, monkeypatch, fault):
+    spec = small_study()
+    fault(monkeypatch, spec, SEED)
+    assert run_small(spec, capsys, seed=SEED)["correct"] is False
+
+
+@pytest.mark.parametrize("noise_left_out", [False, True])
+def test_study_read_noise_follows_the_program(capsys, monkeypatch, noise_left_out):
+    import repro.variability.engine as mc
+
+    if noise_left_out:
+        monkeypatch.setattr(mc, "evaluate_batch", no_read_noise(mc.evaluate_batch))
+    result = run_small(small_study(), capsys, seed=SEED)
+    assert result["correct"] is (not noise_left_out)

@@ -57,7 +57,7 @@ def test_clock_offset_from_the_harness_marks():
 
 
 def test_recorded_chip_trace():
-    t = tracing.Trace.load(str(FIXTURE))
+    t = tracing.Trace.load(str(FIXTURE), labels=("request",))
     assert list(t.device_ops) == ["/device:TPU:0"]
     assert [a[0] for a in t.annotations] == ["request", "request"]
     s = t.summary()
