@@ -39,6 +39,12 @@ class ModelConfig:
     experts_per_token: int = 0
     moe_d_ff: int = 0
     first_k_dense: int = 0      # leading dense layers (deepseek-style)
+    # router (deepseek-style group-limited top-k; core/moe.route)
+    n_group: int = 1            # expert groups
+    topk_group: int = 1         # groups kept per token
+    routed_scaling_factor: float = 1.0
+    scoring_func: str = "softmax"   # softmax | sigmoid
+    norm_topk_prob: bool = False    # normalise the selected scores to sum 1
     moe_every: int = 1          # MoE on layers where (idx % moe_every)==moe_offset
     moe_offset: int = 0
     capacity_factor: float = 1.25
@@ -174,6 +180,8 @@ class ModelConfig:
             n_experts=min(self.n_experts, 8) if self.n_experts else 0,
             n_shared_experts=min(self.n_shared_experts, 1),
             experts_per_token=min(self.experts_per_token, 2) if self.n_experts else 0,
+            n_group=min(self.n_group, 2),
+            topk_group=min(self.topk_group, 1),
             moe_d_ff=64 if self.moe_d_ff else 0,
             first_k_dense=min(self.first_k_dense, 1),
             n_encoder_layers=min(self.n_encoder_layers, 2),

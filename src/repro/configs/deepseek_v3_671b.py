@@ -1,6 +1,8 @@
 """deepseek-v3-671b — MLA + 1 shared/256 routed top-8 MoE + MTP
 [arXiv:2412.19437; hf]. First 3 layers dense (d_ff 18432); MoE expert
-d_ff 2048; MLA q_lora 1536 / kv_lora 512 (+64 rope)."""
+d_ff 2048; MLA q_lora 1536 / kv_lora 512 (+64 rope). Router: sigmoid
+scores, 8 groups of 32 experts ranked by their top-2 biased scores, the
+best 4 kept, top-8 weights normalised and scaled by 2.5 (noaux_tc)."""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
@@ -23,6 +25,11 @@ CONFIG = ModelConfig(
     n_shared_experts=1,
     experts_per_token=8,
     moe_d_ff=2048,
+    n_group=8,
+    topk_group=4,
+    routed_scaling_factor=2.5,
+    scoring_func="sigmoid",
+    norm_topk_prob=True,
     first_k_dense=3,
     mtp=True,
     param_dtype="bfloat16",   # 671B: bf16 params + 8-bit optim state

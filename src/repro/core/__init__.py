@@ -13,6 +13,9 @@ Public API:
   netlist.map_layer / map_imac          (SPICE netlist generation)
   evaluate.test_imac / evaluate_batch / evaluate_netlist / sweep
                                         (Module 1: testIMAC)
+  moe.MoESpec / route; evaluate.map_moe / evaluate_moe
+                                        (one chip's share of an MoE FFN
+                                        layer through the crossbar solve)
 """
 from repro.core.devices import (
     CBRAM,
@@ -26,8 +29,11 @@ from repro.core.devices import (
 )
 from repro.core.evaluate import (
     IMACResult,
+    MoEResult,
     evaluate_batch,
+    evaluate_moe,
     evaluate_netlist,
+    map_moe,
     structure_key,
     sweep,
     test_imac,
@@ -40,6 +46,7 @@ from repro.core.imac import (
     linear_forward,
 )
 from repro.core.interconnect import DEFAULT_INTERCONNECT, Interconnect
+from repro.core.moe import MoESpec
 from repro.core.mapping import MappedLayer, map_network, map_wb
 from repro.core.netlist import (
     map_imac,
@@ -77,6 +84,8 @@ __all__ = [
     "Interconnect",
     "MRAM",
     "MappedLayer",
+    "MoEResult",
+    "MoESpec",
     "NeuronModel",
     "PCM",
     "PartitionPlan",
@@ -91,6 +100,7 @@ __all__ = [
     "custom_tech",
     "default_backend_name",
     "evaluate_batch",
+    "evaluate_moe",
     "evaluate_netlist",
     "get_backend",
     "get_neuron",
@@ -99,6 +109,7 @@ __all__ = [
     "linear_forward",
     "map_imac",
     "map_layer",
+    "map_moe",
     "map_network",
     "map_wb",
     "netlist_stats",

@@ -23,16 +23,25 @@ from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import obs
 from repro.obs import prof as obs_prof
 from repro.core.backends import get_backend
 from repro.core.digital import Params, mlp_forward
-from repro.core.imac import IMACConfig, build_plans, layer_latency, linear_forward
-from repro.core.mapping import MappedLayer, map_network
+from repro.core.imac import (
+    IMACConfig,
+    build_plans,
+    layer_latency,
+    linear_forward,
+    matrix_forward,
+    matrix_plan,
+)
+from repro.core.mapping import MappedLayer, map_matrix, map_network
+from repro.core.moe import SHARED, MoESpec, bucket, dispatch, route
 from repro.core.neurons import NeuronModel
-from repro.core.partition import PartitionPlan, ordered_sum
+from repro.core.partition import PartitionPlan, ordered_sum, tile_matrix
 from repro.core.solver import CircuitParams, SolveOptions, suggest_iters
 from repro.distributed.sweep import (
     MeshPlan,
@@ -376,21 +385,31 @@ def _run_chunk_for(prog: _GroupProgram, noisy: bool, shard):
         mesh, axis, stacked_specs = shard
         leaves, tree = jax.tree_util.tree_flatten(stacked_specs)
         key = (prog, noisy, (mesh, axis, tuple(leaves), tree))
+    return _cached_program(key, lambda: _build_run_chunk(prog, noisy, shard))
+
+
+def _cached_program(key, build):
+    """(program, lookup): the process's program under `key`, else `build()`
+    kept under it; lookup "hit", "miss" or "bypass" (a key that does not
+    hash: built, not kept). Counted in `program_cache_lookups_total`."""
     try:
         hash(key)
     except TypeError:
-        return _build_run_chunk(prog, noisy, shard), "bypass"
-    with _programs_lock:
-        run_chunk = _programs.get(key)
-        if run_chunk is not None:
-            _programs.move_to_end(key)
-            return run_chunk, "hit"
-    run_chunk = _build_run_chunk(prog, noisy, shard)
-    with _programs_lock:
-        _programs[key] = run_chunk
-        while len(_programs) > PROGRAM_CACHE_SIZE:
-            _programs.popitem(last=False)
-    return run_chunk, "miss"
+        lookup, program = "bypass", build()
+    else:
+        with _programs_lock:
+            program = _programs.get(key)
+            if program is not None:
+                _programs.move_to_end(key)
+                lookup = "hit"
+        if program is None:
+            lookup, program = "miss", build()
+            with _programs_lock:
+                _programs[key] = program
+                while len(_programs) > PROGRAM_CACHE_SIZE:
+                    _programs.popitem(last=False)
+    obs.counter("program_cache_lookups_total", {"result": lookup}).inc()
+    return program, lookup
 
 
 def evaluate_batch(
@@ -521,6 +540,17 @@ def _resolve_shard(mesh_plan, n_cfgs, noise_per_config, noise_key):
     return mesh, mesh_plan.axis, mesh.shape[mesh_plan.axis]
 
 
+def _resolved_options(solve_options: Optional[SolveOptions]) -> SolveOptions:
+    """`solve_options` with its backend resolved, as a program key holds it."""
+    if solve_options is None:
+        solve_options = SolveOptions()
+    if solve_options.backend is None or isinstance(solve_options.backend, str):
+        solve_options = dataclasses.replace(
+            solve_options, backend=get_backend(solve_options.backend)
+        )
+    return solve_options
+
+
 def _evaluate_batch(
     params,
     x,
@@ -642,12 +672,7 @@ def _evaluate_batch(
             )
 
     # The group program holds the backend itself (see _GroupProgram).
-    if solve_options is None:
-        solve_options = SolveOptions()
-    if solve_options.backend is None or isinstance(solve_options.backend, str):
-        solve_options = dataclasses.replace(
-            solve_options, backend=get_backend(solve_options.backend)
-        )
+    solve_options = _resolved_options(solve_options)
 
     # Sharded execution: pad the stacked config axis to a multiple of
     # the mesh axis (replicating entry 0 — trip-count-neutral, see
@@ -694,8 +719,7 @@ def _evaluate_batch(
         shard_specs = (s_mesh, s_axis, jax.tree_util.tree_map(
             lambda t: stacked_spec(t, s_mesh, s_axis), (g_pos, g_neg, k, scal)
         ))
-    run_chunk, lookup = _run_chunk_for(prog, noise_key is not None, shard_specs)
-    obs.counter("program_cache_lookups_total", {"result": lookup}).inc()
+    run_chunk, _ = _run_chunk_for(prog, noise_key is not None, shard_specs)
 
     n_chunks = (n + chunk - 1) // chunk
     keys = (
@@ -919,3 +943,333 @@ def sweep(
     solves and memoizes results on disk.
     """
     return [(name, test_imac(params, x, y, cfg, **kw)) for name, cfg in cfgs]
+
+
+# ---------------------------------------------------------------------------
+# A mixture-of-experts FFN layer: one chip's share of the experts.
+# ---------------------------------------------------------------------------
+
+#: Cells (slots x tiles x rows x cols) one chunk of an expert matrix's solve
+#: may hold: 2**26 cells keep a chunk's solve temporaries near 4 GB.
+MOE_CHUNK_CELLS = 1 << 26
+
+
+class MappedExpert(NamedTuple):
+    """One expert's tiles: gate and up side by side, then down."""
+
+    g_up: jax.Array     # (2T, M, N) G+ tiles then G- tiles of [W_gate | W_up]
+    k_up: jax.Array     # (2 * d_expert,) sense scale of each column
+    g_down: jax.Array   # (2T', M, N) tiles of W_down
+    k_down: float
+
+
+@dataclasses.dataclass
+class MoELayer:
+    """This chip's share of an MoE FFN layer, mapped onto crossbar tiles.
+
+    Made once by `map_moe`; every `evaluate_moe` call reuses its tiles.
+    """
+
+    spec: MoESpec
+    cfg: IMACConfig
+    held: "tuple[int, ...]"            # routed experts held here
+    router_w: jax.Array                # (n_experts, d_model)
+    router_bias: jax.Array             # (n_experts,)
+    experts: "dict[int, MappedExpert]"  # SHARED and the held routed ids
+    plan_up: PartitionPlan
+    plan_down: PartitionPlan
+    scalars: dict                      # the electrical values the programs read
+    amp_power: float                   # W of the sense amplifiers of one pair
+
+
+def map_moe(
+    spec: MoESpec,
+    cfg: IMACConfig,
+    router_w: jax.Array,
+    router_bias: jax.Array,
+    experts: "dict[int, tuple[jax.Array, jax.Array, jax.Array]]",
+) -> MoELayer:
+    """Map this chip's experts onto `cfg`'s tiles.
+
+    Args:
+      spec: the layer's shape and router.
+      cfg: technology, tile size, electrical and solver settings (the
+        neuron model gives the sense amplifiers' power; no neuron is
+        used).
+      router_w, router_bias: the full router (every routed expert).
+      experts: expert id (`SHARED`, or a routed id held here) ->
+        (W_gate (d_model, d_expert), W_up (d_model, d_expert),
+        W_down (d_expert, d_model)), in x @ W orientation, no biases.
+        Each matrix is mapped alone (`map_matrix`).
+    """
+    if spec.n_shared not in (0, 1):
+        raise ValueError(f"{spec.n_shared} shared experts: only 0 or 1 supported")
+    if (SHARED in experts) != (spec.n_shared == 1):
+        raise ValueError("the shared expert's weights and spec.n_shared disagree")
+    held = tuple(sorted(e for e in experts if e != SHARED))
+    if any(not 0 <= e < spec.n_experts for e in held):
+        raise ValueError(f"held experts {held} outside 0..{spec.n_experts - 1}")
+    tech = cfg.resolved_tech()
+    dtype = cfg.dtype
+    rows, cols = cfg.array_rows, cfg.array_cols
+    plan_up = matrix_plan(spec.d_model, 2 * spec.d_expert, rows, cols)
+    plan_down = matrix_plan(spec.d_expert, spec.d_model, rows, cols)
+
+    def tiles(g_pos, g_neg, plan):
+        return jnp.concatenate(
+            [tile_matrix(g_pos, plan), tile_matrix(g_neg, plan)]
+        ).astype(dtype)
+
+    mapped = {}
+    with obs.trace("map", {"experts": len(experts)}):
+        for e, (w_gate, w_up, w_down) in experts.items():
+            gp_g, gn_g, k_g = map_matrix(w_gate, tech, quantize=cfg.quantize)
+            gp_u, gn_u, k_u = map_matrix(w_up, tech, quantize=cfg.quantize)
+            g_up = tiles(jnp.concatenate([gp_g, gp_u], axis=1),
+                         jnp.concatenate([gn_g, gn_u], axis=1), plan_up)
+            del gp_g, gn_g, gp_u, gn_u
+            gp_d, gn_d, k_d = map_matrix(w_down, tech, quantize=cfg.quantize)
+            mapped[e] = MappedExpert(
+                g_up=g_up,
+                k_up=jnp.concatenate([jnp.full(spec.d_expert, k_g, dtype),
+                                      jnp.full(spec.d_expert, k_u, dtype)]),
+                g_down=tiles(gp_d, gn_d, plan_down),
+                k_down=k_d,
+            )
+    scalars = {
+        "r_seg": jnp.asarray(cfg.interconnect.r_segment, dtype),
+        "r_source": jnp.asarray(cfg.r_source, dtype),
+        "r_tia": jnp.asarray(cfg.r_tia, dtype),
+        "omega": jnp.asarray(cfg.sor_omega, dtype),
+    }
+    # One TIA and amplifier per tile column of each array of the pair.
+    n_amps = 2 * (plan_up.n_tiles * plan_up.cols + plan_down.n_tiles * plan_down.cols)
+    return MoELayer(
+        spec=spec, cfg=cfg, held=held,
+        router_w=jnp.asarray(router_w, jnp.float32),
+        router_bias=jnp.asarray(router_bias, jnp.float32),
+        experts=mapped, plan_up=plan_up, plan_down=plan_down, scalars=scalars,
+        amp_power=n_amps * cfg.resolved_neuron().p_amp,
+    )
+
+
+class PairDetail(NamedTuple):
+    """The circuit's view of one (token, expert) pair (host arrays)."""
+
+    z_up: np.ndarray         # (2 * d_expert,) sensed gate, then up, outputs
+    h: np.ndarray            # (d_expert,) silu(gate) * up: the down input
+    z_down: np.ndarray       # (d_model,) sensed down outputs: the expert's output
+    tile_power_up: np.ndarray    # (2T,) W per tile, G+ tiles then G- tiles
+    tile_power_down: np.ndarray  # (2T',)
+
+
+class MoEPair(NamedTuple):
+    """One (token, expert) pair computed on this chip."""
+
+    token: int
+    expert: int              # SHARED, or a routed expert id
+    weight: float            # routing weight (1.0 for the shared expert)
+    power: float             # W: its tiles' device power plus sense amplifiers
+    device_power: float      # W: its tiles alone
+    detail: PairDetail
+
+
+class MoEResult(NamedTuple):
+    """This chip's part of an MoE FFN layer for a batch of tokens."""
+
+    out: np.ndarray          # (B, d_model) shared + weighted held experts
+    out_ideal: np.ndarray    # the same with ideal crossbars (no wires)
+    out_rel_err: np.ndarray  # (B,) |out - out_ideal| / |out_ideal|
+    power: np.ndarray        # (B,) W of every pair of the token
+    topk_idx: np.ndarray     # (B, top_k) the router's selection (all chips)
+    topk_weight: np.ndarray  # (B, top_k)
+    pairs: "tuple[MoEPair, ...]"  # shared first, then held experts in order
+
+
+@dataclasses.dataclass(frozen=True)
+class _RouteProgram:
+    """The router program's static inputs (a program-cache key)."""
+
+    spec: MoESpec
+
+
+def route_all(prog: _RouteProgram, x, w_router, bias):
+    return route(x, w_router, bias, prog.spec)
+
+
+@dataclasses.dataclass(frozen=True)
+class _ExpertProgram:
+    """Static inputs of one expert matrix's program (a program-cache key):
+    stage "up" runs x -> (gate, up) -> silu(gate) * up, stage "down" runs
+    h -> down; each for `slots` tokens, circuit and ideal crossbar."""
+
+    stage: str
+    plan: PartitionPlan
+    slots: int
+    chunks: int
+    iters: int
+    tol: float
+    v_unit: float
+    dtype: jnp.dtype
+    solve_options: SolveOptions
+
+    def forward(self, g, k, sc, x, x_ideal):
+        """(z, z_ideal, tile_power, sweeps): the circuit on `x`, the ideal
+        crossbar on `x_ideal`."""
+        cp = CircuitParams(
+            r_row=sc["r_seg"], r_col=sc["r_seg"], r_source=sc["r_source"],
+            r_tia=sc["r_tia"], gs_iters=self.iters, omega=sc["omega"],
+            tol=self.tol,
+        )
+        kw = dict(v_unit=self.v_unit, chunks=self.chunks, dtype=self.dtype)
+        z, power, sweeps = matrix_forward(
+            g, k, self.plan, cp, x, solve_options=self.solve_options, **kw
+        )
+        z_ideal, _, _ = matrix_forward(
+            g, k, self.plan, cp, x_ideal, parasitics=False, **kw
+        )
+        return z, z_ideal, power, sweeps
+
+
+def expert_up(prog: _ExpertProgram, g, k, sc, x, slots):
+    """Gate and up of the tokens in `slots`, and the down inputs."""
+    x = jnp.take(x, slots, axis=0)
+    z, z_ideal, power, sweeps = prog.forward(g, k, sc, x, x)
+    f = z.shape[-1] // 2
+    h = jax.nn.silu(z[:, :f]) * z[:, f:]
+    h_ideal = jax.nn.silu(z_ideal[:, :f]) * z_ideal[:, f:]
+    return z, h, h_ideal, power, sweeps
+
+
+def expert_down(prog: _ExpertProgram, g, k, sc, h, h_ideal):
+    """Down of the circuit's and the ideal crossbar's down inputs."""
+    return prog.forward(g, k, sc, h, h_ideal)
+
+
+def _chunks(plan: PartitionPlan, slots: int) -> int:
+    """Fewest equal chunks of a matrix's 2T tiles within MOE_CHUNK_CELLS."""
+    n_sys = 2 * plan.n_tiles
+    cells = slots * plan.rows * plan.cols
+    for c in range(1, n_sys + 1):
+        if n_sys % c == 0 and cells * (n_sys // c) <= MOE_CHUNK_CELLS:
+            return c
+    return n_sys
+
+
+def evaluate_moe(
+    layer: MoELayer,
+    x: jax.Array,
+    *,
+    solve_options: Optional[SolveOptions] = None,
+) -> MoEResult:
+    """This chip's part of the MoE FFN layer for a batch of tokens.
+
+    The router runs over every routed expert. The shared expert runs on
+    every token; each held expert on the tokens that selected it, padded
+    to `bucket(n)` slots (pad slots repeat a real token and are dropped).
+    Each expert is two programs, cached per (stage, matrix, slots) like
+    `evaluate_batch`'s group programs: gate and up through the circuit
+    solve, silu(gate) * up digitally, then down through the solve. The
+    same programs compute the ideal crossbar (no wires) of the same
+    conductances, which `out_rel_err` compares against. Unselected
+    experts' tiles are not solved and draw no power.
+
+    Args:
+      layer: this chip's mapped share (`map_moe`).
+      x: (B, d_model) hidden states.
+      solve_options: circuit-solver backend selection; None = the process
+        default.
+    """
+    x = jnp.asarray(x, jnp.float32)
+    with obs.trace("evaluate_moe", {"tokens": int(x.shape[0])}):
+        return _evaluate_moe(layer, x, _resolved_options(solve_options))
+
+
+def _evaluate_moe(layer: MoELayer, x, solve_options) -> MoEResult:
+    spec, cfg = layer.spec, layer.cfg
+    n_tok = x.shape[0]
+    with obs.trace("route"):
+        run_route, _ = _cached_program(
+            _RouteProgram(spec),
+            lambda: jax.jit(_bind(route_all, _RouteProgram(spec))),
+        )
+        idx, weight = jax.device_get(
+            run_route(x, layer.router_w, layer.router_bias)
+        )
+        work = []
+        if SHARED in layer.experts:
+            work.append((SHARED, np.arange(n_tok), np.ones(n_tok, np.float32)))
+        work += dispatch(idx, weight, layer.held)
+        slots = []
+        for e, tokens, _ in work:
+            n = bucket(len(tokens))
+            slots.append(np.concatenate(
+                [tokens, np.full(n - len(tokens), tokens[0])]).astype(np.int32))
+            kind = "shared" if e == SHARED else "routed"
+            obs.counter("moe_expert_tokens_total", {"kind": kind}).inc(len(tokens))
+            obs.counter("moe_expert_slots_total", {"kind": "real"}).inc(len(tokens))
+            obs.counter("moe_expert_slots_total", {"kind": "pad"}).inc(n - len(tokens))
+
+    with obs.trace("prepare", {"experts": len(work)}):
+        iters = cfg.gs_iters or suggest_iters(cfg.array_rows, cfg.array_cols)
+        programs = []
+        for s in slots:
+            stages = []
+            for stage, fn, plan in (("up", expert_up, layer.plan_up),
+                                    ("down", expert_down, layer.plan_down)):
+                prog = _ExpertProgram(
+                    stage=stage, plan=plan, slots=len(s), chunks=_chunks(plan, len(s)),
+                    iters=iters, tol=cfg.gs_tol, v_unit=cfg.vdd,
+                    dtype=jnp.dtype(cfg.dtype), solve_options=solve_options,
+                )
+                stages.append(_cached_program(
+                    prog, lambda fn=fn, prog=prog: jax.jit(_bind(fn, prog)))[0])
+            programs.append(stages)
+
+    sc = layer.scalars
+    outs = []
+    with obs.trace("solve", {"experts": len(work), "tokens": n_tok}):
+        for (e, _, _), s, (run_up, run_down) in zip(work, slots, programs):
+            ex = layer.experts[e]
+            z_up, h, h_ideal, p_up, sw_up = run_up(ex.g_up, ex.k_up, sc, x, s)
+            y, y_ideal, p_down, sw_down = run_down(ex.g_down, ex.k_down, sc, h, h_ideal)
+            outs.append((z_up, h, y, y_ideal, p_up, p_down, sw_up, sw_down))
+    if obs.enabled():
+        with obs.trace("device_wait"):
+            jax.block_until_ready(outs)
+    obs_prof.sample_memory("solve")
+
+    with obs.trace("measure"):
+        outs = jax.device_get(outs)
+        if obs.enabled():
+            h_sw = obs.histogram("solver_sweeps", buckets=obs.SWEEPS_BUCKETS)
+            for o in outs:
+                for value in np.concatenate([o[6], o[7]]):
+                    h_sw.observe(int(value))
+        d = spec.d_model
+        out = np.zeros((n_tok, d), np.float32)
+        out_ideal = np.zeros((n_tok, d), np.float32)
+        power = np.zeros(n_tok)
+        pairs = []
+        for (e, tokens, w), (z_up, h, y, y_ideal, p_up, p_down, _, _) in zip(work, outs):
+            n = len(tokens)
+            out[tokens] += w[:, None] * y[:n]
+            out_ideal[tokens] += w[:, None] * y_ideal[:n]
+            dev = (np.sum(p_up[:n], axis=1, dtype=np.float64)
+                   + np.sum(p_down[:n], axis=1, dtype=np.float64))
+            for i, t in enumerate(tokens):
+                power[t] += dev[i] + layer.amp_power
+                pairs.append(MoEPair(
+                    token=int(t), expert=e, weight=float(w[i]),
+                    power=float(dev[i] + layer.amp_power),
+                    device_power=float(dev[i]),
+                    detail=PairDetail(z_up[i], h[i], y[i], p_up[i], p_down[i]),
+                ))
+        norm = np.linalg.norm(out_ideal, axis=1)
+        out_rel_err = np.linalg.norm(out - out_ideal, axis=1) / np.where(norm > 0, norm, 1.0)
+    obs_prof.sample_memory("measure")
+    return MoEResult(
+        out=out, out_ideal=out_ideal, out_rel_err=out_rel_err, power=power,
+        topk_idx=np.asarray(idx), topk_weight=np.asarray(weight),
+        pairs=tuple(pairs),
+    )

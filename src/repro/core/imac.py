@@ -14,6 +14,7 @@ Fast paths:
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import TYPE_CHECKING, NamedTuple, Optional, Sequence
 
 if TYPE_CHECKING:  # pragma: no cover - annotation only, avoids a cycle
@@ -245,6 +246,87 @@ def linear_forward(
     p_iface = n_amps * neuron.p_amp + n_neurons * neuron.p_neuron
     power = p_dev + p_iface
     return act, power, residual, z, sweeps
+
+
+def matrix_plan(fan_in: int, fan_out: int, rows: int, cols: int) -> PartitionPlan:
+    """Tiling of a bias-free (fan_in, fan_out) matrix on rows x cols arrays:
+    the fewest partitions that fit, with no bias row."""
+    hp = math.ceil(fan_in / rows)
+    vp = math.ceil(fan_out / cols)
+    return PartitionPlan(
+        hp=hp, vp=vp, rows=math.ceil(fan_in / hp), cols=math.ceil(fan_out / vp),
+        total_rows=fan_in, total_cols=fan_out,
+    )
+
+
+def matrix_forward(
+    g_tiles: jax.Array,
+    k: "jax.Array | float",
+    plan: PartitionPlan,
+    cp: CircuitParams,
+    x: jax.Array,
+    *,
+    v_unit: float,
+    chunks: int = 1,
+    parasitics: bool = True,
+    solve_options: Optional[SolveOptions] = None,
+    dtype: jnp.dtype = jnp.float32,
+) -> "tuple[jax.Array, jax.Array, jax.Array]":
+    """Analog MVM of a weight matrix with no bias, no neuron and signed inputs.
+
+    Each token's inputs are scaled digitally so that its largest magnitude
+    drives `v_unit` volts (negative inputs drive negative volts); the scale
+    is undone after sensing, so z approximates x @ W.
+
+    Args:
+      g_tiles: (2T, M, N) conductances: the T tiles of G+ (`tile_matrix`
+        order, tile t = h * vp + v), then the T tiles of G-.
+      k: sense scale (S per unit weight), a scalar or one per output column.
+      x: (B, fan_in) inputs in digital units.
+      chunks: the tiles are solved in this many equal chunks, one after
+        another, which bounds the solve's temporaries.
+      parasitics: False = ideal crossbar (I = G^T V) on the same tiles.
+
+    Returns:
+      (z, tile_power, sweeps): z (B, fan_out) sensed outputs; tile_power
+      (B, 2T) device power of each tile (W); sweeps (chunks,) the
+      Gauss-Seidel sweeps of each chunk's solve (0 for the ideal crossbar).
+    """
+    x = x.astype(dtype)
+    peak = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
+    scale = jnp.where(peak > 0, peak, jnp.ones_like(peak))
+    v = x * (v_unit / scale)
+    v_tiles = tile_inputs(v, plan)                          # (B, hp, M)
+    v_per_tile = jnp.repeat(v_tiles, plan.vp, axis=-2)      # (B, T, M)
+    v_all = jnp.concatenate([v_per_tile, v_per_tile], axis=-2)  # (B, 2T, M)
+    g_tiles = g_tiles.astype(dtype)
+    n_sys, m, n = g_tiles.shape
+    b = x.shape[0]
+    if parasitics:
+        g_c = g_tiles.reshape(chunks, n_sys // chunks, m, n)
+        v_c = jnp.moveaxis(v_all.reshape(b, chunks, n_sys // chunks, m), 1, 0)
+
+        def solve_chunk(args):
+            g, vv = args
+            g_b = g[None]                                   # (1, c, M, N)
+            sol = solve_crossbar(g_b, vv, cp, options=solve_options)
+            power = crossbar_power(g_b, vv, sol, cp)        # (B, c)
+            return sol.i_out, power, jnp.asarray(sol.sweeps, jnp.int32)
+
+        i_out, tile_power, sweeps = jax.lax.map(solve_chunk, (g_c, v_c))
+        i_out = jnp.moveaxis(i_out, 0, 1).reshape(b, n_sys, n)
+        tile_power = jnp.moveaxis(tile_power, 0, 1).reshape(b, n_sys)
+    else:
+        highest = jax.lax.Precision.HIGHEST
+        i_out = jnp.einsum("btm,tmn->btn", v_all, g_tiles, precision=highest)
+        tile_power = jnp.einsum(
+            "btm,tmn->bt", v_all**2, g_tiles, precision=highest
+        )
+        sweeps = jnp.zeros((chunks,), jnp.int32)
+    t = plan.n_tiles
+    i_diff = combine_outputs(i_out[:, :t], plan) - combine_outputs(i_out[:, t:], plan)
+    z = i_diff * scale / (jnp.asarray(k, dtype) * v_unit)
+    return z, tile_power, sweeps
 
 
 def layer_latency(plan: PartitionPlan, interconnect: Interconnect, neuron) -> float:

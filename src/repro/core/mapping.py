@@ -96,16 +96,7 @@ def map_wb(
     if w_scale <= 0.0:
         w_scale = 1.0
     wn = wb / w_scale  # in [-1, 1]
-
-    g_pos = tech.g_off + jnp.maximum(wn, 0.0) * tech.g_range
-    g_neg = tech.g_off + jnp.maximum(-wn, 0.0) * tech.g_range
-    if quantize:
-        g_pos = tech.quantize(g_pos)
-        g_neg = tech.quantize(g_neg)
-    if variation_key is not None:
-        kp, kn = jax.random.split(variation_key)
-        g_pos = tech.perturb(kp, g_pos)
-        g_neg = tech.perturb(kn, g_neg)
+    g_pos, g_neg = _conductance_pair(wn, tech, quantize, variation_key)
 
     k = tech.g_range / w_scale
     sense_r = 1.0 / (k * 1.0)  # z = I_diff / (k * v_unit) * 1.0; see below
@@ -118,6 +109,42 @@ def map_wb(
         sense_r=sense_r,
         v_unit=v_unit,
     )
+
+
+def _conductance_pair(wn, tech, quantize, variation_key=None):
+    """(G+, G-) of weights normalised to [-1, 1]."""
+    g_pos = tech.g_off + jnp.maximum(wn, 0.0) * tech.g_range
+    g_neg = tech.g_off + jnp.maximum(-wn, 0.0) * tech.g_range
+    if quantize:
+        g_pos = tech.quantize(g_pos)
+        g_neg = tech.quantize(g_neg)
+    if variation_key is not None:
+        kp, kn = jax.random.split(variation_key)
+        g_pos = tech.perturb(kp, g_pos)
+        g_neg = tech.perturb(kn, g_neg)
+    return g_pos, g_neg
+
+
+def map_matrix(
+    weights: jax.Array,
+    tech: DeviceTech,
+    *,
+    quantize: bool = True,
+) -> "tuple[jax.Array, jax.Array, float]":
+    """Map a bias-free (fan_in, fan_out) weight matrix.
+
+    The matrix is scaled by its largest magnitude onto [G_off, G_on], as
+    `map_wb` does, with no bias row. Returns (G+, G-, k) with
+    (G+ - G-) = k * W.
+    """
+    weights = jnp.asarray(weights)
+    if weights.ndim != 2:
+        raise ValueError(f"bad shape: weights {weights.shape}")
+    w_scale = float(jnp.max(jnp.abs(weights)))
+    if w_scale <= 0.0:
+        w_scale = 1.0
+    g_pos, g_neg = _conductance_pair(weights / w_scale, tech, quantize)
+    return g_pos, g_neg, tech.g_range / w_scale
 
 
 def map_network(

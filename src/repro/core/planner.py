@@ -32,6 +32,7 @@ class MatrixPlan:
     count: int          # how many instances (layers x experts ...)
     hp: int
     vp: int
+    activity: float = 1.0   # share of tokens that drive it (routed experts)
 
     @property
     def tiles_per_instance(self) -> int:
@@ -56,7 +57,7 @@ class DeploymentReport:
     matrices: "List[MatrixPlan]"
     total_tiles: int
     total_devices: int
-    est_power_w: float       # all tiles active, mean conductance
+    est_power_w: float       # tiles at their activity, mean conductance
     est_latency_ns: float    # one analog MVM through the deepest layer
     area_mm2: float          # bitcell-pitch estimate
 
@@ -73,8 +74,10 @@ class DeploymentReport:
         }
 
 
-def _arch_matrices(cfg: ModelConfig) -> "List[MatrixPlan]":
-    """Enumerate the static projection matrices of one arch."""
+def _arch_matrices(cfg: ModelConfig) -> "list[tuple[str, int, int, int, float]]":
+    """The static projection matrices of one arch: (name, fan_in, fan_out,
+    count, activity). A routed expert's matrices are driven by
+    experts_per_token / n_experts of the tokens; every other matrix by all."""
     e, f = cfg.d_model, cfg.d_ff
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     out: "list[tuple[str, int, int, int]]" = []
@@ -114,16 +117,24 @@ def _arch_matrices(cfg: ModelConfig) -> "List[MatrixPlan]":
                 ("mlp_up", e, f, n_dense),
                 ("mlp_down", f, e, n_dense),
             ]
-        if n_moe:
-            fe = cfg.moe_d_ff
-            n_exp = cfg.n_experts + cfg.n_shared_experts
+        if n_moe and cfg.n_shared_experts:
+            fe, n_sh = cfg.moe_d_ff, n_moe * cfg.n_shared_experts
             out += [
-                ("moe_gate", e, fe, n_moe * n_exp),
-                ("moe_up", e, fe, n_moe * n_exp),
-                ("moe_down", fe, e, n_moe * n_exp),
+                ("shared_gate", e, fe, n_sh),
+                ("shared_up", e, fe, n_sh),
+                ("shared_down", fe, e, n_sh),
             ]
     out += [("lm_head", e, cfg.vocab, 1)]
-    return out
+    matrices = [(*m, 1.0) for m in out]
+    if n_moe:
+        fe, n_exp = cfg.moe_d_ff, n_moe * cfg.n_experts
+        activity = cfg.experts_per_token / cfg.n_experts
+        matrices += [
+            ("moe_gate", e, fe, n_exp, activity),
+            ("moe_up", e, fe, n_exp, activity),
+            ("moe_down", fe, e, n_exp, activity),
+        ]
+    return matrices
 
 
 def plan_arch(
@@ -136,27 +147,31 @@ def plan_arch(
     neuron = get_neuron("sigmoid")
     ic = DEFAULT_INTERCONNECT
     plans = []
-    for name, fi, fo, count in _arch_matrices(cfg):
+    for name, fi, fo, count, activity in _arch_matrices(cfg):
         hp, vp = auto_partition(fi, fo, array_rows, array_cols)
-        plans.append(MatrixPlan(name, fi, fo, count, hp, vp))
+        plans.append(MatrixPlan(name, fi, fo, count, hp, vp, activity))
 
     total_tiles = sum(p.tiles for p in plans)
     total_devices = sum(p.devices for p in plans)
 
-    # Power: mean device conductance at half swing + interface circuits.
+    # Power: mean device conductance at half swing + interface circuits,
+    # each matrix weighted by the share of tokens that drive it.
     g_mean = 0.5 * (tech.g_on + tech.g_off)
     v_mean = 0.5 * neuron.vdd
     p_device = g_mean * v_mean**2
-    n_cols_active = sum(p.fan_out * p.count * p.hp for p in plans)
-    est_power = (
-        total_devices * p_device
-        + n_cols_active * 2 * neuron.p_amp
-        + sum(p.fan_out * p.count for p in plans) * neuron.p_neuron
+    est_power = sum(
+        p.activity * (
+            p.devices * p_device
+            + p.fan_out * p.count * p.hp * 2 * neuron.p_amp
+            + p.fan_out * p.count * neuron.p_neuron
+        )
+        for p in plans
     )
 
-    # Latency: deepest chain = layers in sequence; per-tile Elmore.
+    # Latency: deepest chain = layers in sequence, two analog MVMs a layer
+    # (attention or mixer projections, then the FFN); per-tile Elmore.
     t_tile = 4.6 * (ic.elmore_delay(array_cols) + ic.elmore_delay(array_rows))
-    depth = cfg.n_layers * (2 if cfg.moe_enabled or cfg.d_ff else 2)
+    depth = 2 * cfg.n_layers
     est_latency = depth * (t_tile + neuron.t_settle)
 
     # Area: bitcell pitch^2 per device (2 devices/weight already counted).

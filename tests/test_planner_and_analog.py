@@ -3,8 +3,11 @@ import dataclasses
 
 import jax
 import numpy as np
+import pytest
 
 from repro.configs import ARCHS, get_config
+from repro.core.devices import PCM
+from repro.core.neurons import get_neuron
 from repro.core.planner import plan_arch
 from repro.models import build_model
 
@@ -62,3 +65,25 @@ def test_analog_mvm_mode_close_to_digital():
     corr = np.corrcoef(d, a)[0, 1]
     assert corr > 0.95, corr
     assert not np.allclose(d, a)  # the non-idealities are actually applied
+
+
+def test_planner_counts_routed_experts_at_their_activity():
+    """DeepSeek-V3: 58 MoE layers of 256 routed experts, 8 a token, and one
+    shared expert. Every routed tile exists, but draws power for 8/256 of the
+    tokens; the shared expert's for all of them."""
+    cfg = ARCHS["deepseek-v3-671b"]
+    rep = plan_arch(cfg, "PCM")
+    mat = {p.name: p for p in rep.matrices}
+    assert (mat["moe_gate"].count, mat["moe_gate"].activity) == (58 * 256, 8 / 256)
+    assert (mat["shared_gate"].count, mat["shared_gate"].activity) == (58, 1.0)
+    all_on = plan_arch(dataclasses.replace(cfg, experts_per_token=256), "PCM")
+    assert rep.total_tiles == all_on.total_tiles
+    assert rep.total_devices == all_on.total_devices
+    routed = [p for p in all_on.matrices if p.name.startswith("moe_")]
+    assert all(p.activity == 1.0 for p in routed)
+    p_device = 0.5 * (PCM.g_on + PCM.g_off) * (0.5 * 0.8) ** 2
+    neuron = get_neuron("sigmoid")
+    routed_w = sum(p.devices * p_device + p.fan_out * p.count * p.hp * 2 * neuron.p_amp
+                   + p.fan_out * p.count * neuron.p_neuron for p in routed)
+    assert rep.est_power_w == pytest.approx(
+        all_on.est_power_w - (1 - 8 / 256) * routed_w, rel=1e-12)
